@@ -1,0 +1,9 @@
+"""Device idle share while an encode call was in flight: 100 * (1 - union of device-op
+intervals / in-flight time), from the profiler trace, mean over the
+cell's chips."""
+
+
+def read(run):
+    if run.timeline is None:
+        return None
+    return run.timeline.idle_pct("encode", list(range(run.chips)))
