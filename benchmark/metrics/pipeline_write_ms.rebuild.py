@@ -1,0 +1,13 @@
+"""Mean time per slab the EC file pipeline's rebuild spent writing its
+rebuilt shard rows (shard-writer threads, summed over threads: writer
+thread-ms per slab): delta of SeaweedFS_ec_pipeline_seconds{op=rebuild,
+phase=write} over delta of SeaweedFS_ec_pipeline_slabs{op=rebuild} in
+the window, in ms (program counters)."""
+
+
+def read(run):
+    slabs = run.delta("SeaweedFS_ec_pipeline_slabs", op="rebuild")
+    if slabs <= 0:
+        return None
+    return 1e3 * run.delta("SeaweedFS_ec_pipeline_seconds", op="rebuild",
+                           phase="write") / slabs

@@ -318,12 +318,13 @@ class EcFuture:
 
     After resolution the future carries the dispatch attribution the
     tracing plane surfaces (ISSUE 7): how long the slab queued in its
-    lane, how many slabs shared its stacked dispatch, which chip ran
-    it, and the dispatch submission wall. Stamped BEFORE the result is
-    set so a woken consumer never reads half-stamped attribution."""
+    lane, how many slabs shared its stacked dispatch, and which chip ran
+    it. Stamped BEFORE the result is set so a woken consumer never reads
+    half-stamped attribution. The batch's own time is the span
+    `ec.dispatch.<lane>` around its pack and coder call."""
 
     __slots__ = ("_event", "_value", "_error", "_sched", "_key",
-                 "queue_wait_s", "batch_slabs", "chip", "dispatch_wall_s")
+                 "queue_wait_s", "batch_slabs", "chip")
 
     def __init__(self, sched: "EcDispatchScheduler", key: tuple):
         self._event = threading.Event()
@@ -334,7 +335,6 @@ class EcFuture:
         self.queue_wait_s = None
         self.batch_slabs = None
         self.chip = None
-        self.dispatch_wall_s = None
 
     def _set(self, value) -> None:
         self._value = value
@@ -375,13 +375,16 @@ class EcFuture:
 
 
 class _Slab:
-    __slots__ = ("data", "width", "fut", "t")
+    __slots__ = ("data", "width", "fut", "t", "ctx")
 
     def __init__(self, data: np.ndarray, fut: EcFuture):
         self.data = data
         self.width = data.shape[-1]
         self.fut = fut
         self.t = time.perf_counter()
+        # the submitter's span: parent of the batch span, whichever
+        # thread ends up flushing the lane
+        self.ctx = trace.current_context()
 
 
 _schedulers: "weakref.WeakSet[EcDispatchScheduler]" = weakref.WeakSet()
@@ -459,24 +462,27 @@ def reconstruct_now(coder, present_ids, stacked,
 
     When the caller is inside a trace span (a degraded S3 GET), the
     scheduler's per-slab attribution — queue wait, realized batch
-    factor, chip, dispatch wall — lands on that span: the per-request
-    answer to "was this read slow because of the device or the queue"."""
+    factor, chip — lands on that span: the per-request answer to "was
+    this read slow because of the device or the queue". Under it, the
+    span `ec.dispatch.wait` runs from submit until the rows are host
+    bytes."""
     present_ids = tuple(present_ids)
     want = tuple(want) if want is not None else None
     sched = maybe_scheduler(coder)
     if sched is not None:
-        fut = sched.reconstruct_stacked(
-            present_ids, stacked, data_only=data_only, want=want)
-        out = fut.result()
         sp = trace.current()
+        with trace.span("ec.dispatch.wait", child_only=True):
+            fut = sched.reconstruct_stacked(
+                present_ids, stacked, data_only=data_only, want=want)
+            missing, rows = fut.result()
+            rows = np.asarray(rows)
         if sp is not None and fut.batch_slabs is not None:
             sp.set_attr(
                 dispatchQueueWaitMs=round((fut.queue_wait_s or 0) * 1e3,
                                           3),
                 dispatchBatchSlabs=fut.batch_slabs,
-                dispatchChip=fut.chip,
-                dispatchWallMs=round((fut.dispatch_wall_s or 0) * 1e3, 3))
-        return out
+                dispatchChip=fut.chip)
+        return missing, rows
     fn = getattr(coder, "reconstruct_stacked", None)
     if fn is not None:
         if want is not None:
@@ -824,8 +830,8 @@ class EcDispatchScheduler:
         EC_DISPATCH_BATCHES.inc(lane=kind, chip=label,
                                 reason=self._lane_reason(device))
         EC_DISPATCH_STACK_SLABS.observe(len(slabs), lane=kind)
-        EC_DISPATCH_STACK_BYTES.observe(
-            sum(s.data.nbytes for s in slabs), lane=kind)
+        nbytes = sum(s.data.nbytes for s in slabs)
+        EC_DISPATCH_STACK_BYTES.observe(nbytes, lane=kind)
         for s in slabs:
             EC_DISPATCH_WINDOW_WAIT.observe(now - s.t, lane=kind,
                                             chip=label)
@@ -839,16 +845,20 @@ class EcDispatchScheduler:
         # dispatch time turns into batching for the next elevator.
         # Per-chip sub-dispatches are plain per-device jits — submission
         # still serializes here (it's cheap), but EXECUTION proceeds on
-        # every chip's own queue concurrently.
-        try:
-            if key[0] == "enc":
-                self._dispatch_encode(slabs, device)
-            else:
-                self._dispatch_reconstruct(key, slabs, device)
-        except BaseException as e:
-            for s in slabs:
-                if not s.fut.done():
-                    s.fut._set_error(e)
+        # every chip's own queue concurrently. The batch span times the
+        # pack and the coder call on this (the flushing) thread.
+        with trace.span(f"ec.dispatch.{kind}", parent=slabs[0].ctx,
+                        child_only=True, slabs=len(slabs),
+                        bytes=nbytes, chip=label):
+            try:
+                if key[0] == "enc":
+                    self._dispatch_encode(slabs, device)
+                else:
+                    self._dispatch_reconstruct(key, slabs, device)
+            except BaseException as e:
+                for s in slabs:
+                    if not s.fut.done():
+                        s.fut._set_error(e)
 
     def _lane_reason(self, device) -> str:
         """WHY this lane dispatched where it did — the `reason` label on
@@ -878,22 +888,11 @@ class EcDispatchScheduler:
             return out
         return self.coder.encode_parity(wide)
 
-    @staticmethod
-    def _stamp_wall(slabs: list[_Slab], t0: float) -> None:
-        """Dispatch submission wall onto every future BEFORE any _set —
-        a consumer wakes on _set and must find the attribution whole.
-        (On async jax backends this is submission+transfer wall, not
-        device execution; on the CPU coder it is the real wall.)"""
-        wall = time.perf_counter() - t0
-        for s in slabs:
-            s.fut.dispatch_wall_s = wall
-
     def _dispatch_encode(self, slabs: list[_Slab], device=None) -> None:
         fn_on = (getattr(self.coder, "encode_parity_stacked_on", None)
                  if device is not None else None)
         fn_wide_on = (getattr(self.coder, "encode_parity_on", None)
                       if device is not None else None)
-        t0 = time.perf_counter()
         if len(slabs) == 1:
             # lone slab: NO stack copy on ANY lane (ISSUE 12 satellite —
             # PR 5 gave chip lanes the [None] view; non-chip lanes now
@@ -906,14 +905,11 @@ class EcDispatchScheduler:
                 out0 = fn_on(s.data[None], device)[0]
             else:
                 out0 = self._host_encode(s.data)
-            self._stamp_wall(slabs, t0)
             s.fut._set(out0)
             return
         if not hasattr(self.coder, "encode_parity_stacked"):
             for s in slabs:  # exotic coder: amortization off, bytes same
-                t_s = time.perf_counter()  # per-slab wall, not cumulative
                 out0 = self.coder.encode_parity(s.data)
-                self._stamp_wall([s], t_s)
                 s.fut._set(out0)
             return
         if getattr(self.coder, "prefers_vstack", False) and device is None:
@@ -926,7 +922,6 @@ class EcDispatchScheduler:
             except BaseException:
                 self._arena_drop(buf)
                 raise
-            self._stamp_wall(slabs, t0)
             # ragged tails ride zero-padded columns; zero columns encode
             # to zero parity and are sliced away, so per-slab bytes are
             # identical to a lone dispatch (tests/test_ec_dispatch.py)
@@ -954,7 +949,6 @@ class EcDispatchScheduler:
         except BaseException:
             self._arena_drop(buf)
             raise
-        self._stamp_wall(slabs, t0)
         off = 0
         for s in slabs:
             s.fut._set(out[:, off: off + s.width])
@@ -964,13 +958,10 @@ class EcDispatchScheduler:
     def _dispatch_reconstruct(self, key: tuple, slabs: list[_Slab],
                               device=None) -> None:
         _, _geom, present_ids, data_only, want = key
-        t0 = time.perf_counter()
         if not hasattr(self.coder, "reconstruct_stacked"):
             for s in slabs:  # exotic coder: per-slab dict reconstruct
-                t_s = time.perf_counter()  # per-slab wall, not cumulative
                 out0 = reconstruct_stacked_via_dict(
                     self.coder, present_ids, s.data, data_only)
-                self._stamp_wall([s], t_s)
                 s.fut._set(out0)
             return
         chips = self._chip_list()
@@ -994,7 +985,6 @@ class EcDispatchScheduler:
             except BaseException:
                 self._arena_drop(buf)
                 raise
-            self._stamp_wall(slabs, t0)
             for i, s in enumerate(slabs):
                 s.fut._set((missing, rows[i]))
             self._arena_release(buf, rows)
@@ -1022,7 +1012,6 @@ class EcDispatchScheduler:
 
         if len(slabs) == 1:
             out0 = recon(slabs[0].data)
-            self._stamp_wall(slabs, t0)
             slabs[0].fut._set(out0)
             return
         # column-concatenation into a recycled arena view (the old
@@ -1033,7 +1022,6 @@ class EcDispatchScheduler:
         except BaseException:
             self._arena_drop(buf)
             raise
-        self._stamp_wall(slabs, t0)
         off = 0
         for s in slabs:
             s.fut._set((missing, rows[:, off: off + s.width]))

@@ -714,67 +714,73 @@ class VolumeServer:
                                               locs, exclude, tsp)
         if out is not None:
             return out
-        bufs: dict[int, np.ndarray] = {}
-        for i, f in ev.shard_files.items():
-            if i in exclude:
-                continue
-            try:
-                failpoint.fail("ec.shard.read",
-                               ctx=f"{self.address}, shard={i},")
-                data = f.read_at(soff, size)
-            except OSError:  # includes injected FailpointError
-                continue  # survivor set shrinks; any k still suffice
-            bufs[i] = np.frombuffer(data + b"\0" * (size - len(data)), np.uint8)
-
-        missing = [
-            i for i in range(geo.total_shards)
-            if i not in bufs and i != sid and i not in exclude
-            and locs.get(i)
-        ]
-
-        def fetch(i):
-            for addr in locs.get(i, []):
-                if addr == self.address:
+        # the survivor gather: local preads, then remote fetches until
+        # sid is spanned
+        with trace.span("volume.ec.survivor_read", child_only=True,
+                        plan="any_k") as rsp:
+            bufs: dict[int, np.ndarray] = {}
+            for i, f in ev.shard_files.items():
+                if i in exclude:
                     continue
                 try:
-                    return i, np.frombuffer(
-                        self._remote_shard_read(addr, vid, i, soff, size), np.uint8)
-                except grpc.RpcError:
-                    continue
-            return i, None
+                    failpoint.fail("ec.shard.read",
+                                   ctx=f"{self.address}, shard={i},")
+                    data = f.read_at(soff, size)
+                except OSError:  # includes injected FailpointError
+                    continue  # survivor set shrinks; any k still suffice
+                bufs[i] = np.frombuffer(data + b"\0" * (size - len(data)),
+                                        np.uint8)
 
-        from ..models.geometry import UnsolvableError
+            missing = [
+                i for i in range(geo.total_shards)
+                if i not in bufs and i != sid and i not in exclude
+                and locs.get(i)
+            ]
 
-        try:
-            geom = geo.code_geometry()
-        except ValueError:
-            geom = None
+            def fetch(i):
+                for addr in locs.get(i, []):
+                    if addr == self.address:
+                        continue
+                    try:
+                        return i, np.frombuffer(self._remote_shard_read(
+                            addr, vid, i, soff, size), np.uint8)
+                    except grpc.RpcError:
+                        continue
+                return i, None
 
-        def solvable() -> bool:
-            # RS: any k survivors decode (the historical count check).
-            # Non-RS: k survivors may be rank-deficient for THIS shard
-            # (e.g. a local parity among them) — ask the solver, so the
-            # remote fetch keeps going until sid is actually spanned.
-            if geom is None or geom.is_rs:
-                return len(bufs) >= geo.data_shards
-            if sid in bufs:
-                return True
+            from ..models.geometry import UnsolvableError
+
             try:
-                geom.repair_matrix(tuple(sorted(bufs)), (sid,))
-                return True
-            except (UnsolvableError, ValueError):
-                return False
+                geom = geo.code_geometry()
+            except ValueError:
+                geom = None
 
-        if not solvable() and missing:
-            # lint: allow-executor — lazy ex.map + early break once the
-            # solver is satisfied needs a scoped pool whose exit joins
-            # the stragglers; bounded by the shard count (<= 13 tasks)
-            with ThreadPoolExecutor(max_workers=8) as ex:
-                for i, arr in ex.map(fetch, missing):
-                    if arr is not None:
-                        bufs[i] = arr
-                    if solvable():
-                        break
+            def solvable() -> bool:
+                # RS: any k survivors decode (the historical count check).
+                # Non-RS: k survivors may be rank-deficient for THIS shard
+                # (e.g. a local parity among them) — ask the solver, so the
+                # remote fetch keeps going until sid is actually spanned.
+                if geom is None or geom.is_rs:
+                    return len(bufs) >= geo.data_shards
+                if sid in bufs:
+                    return True
+                try:
+                    geom.repair_matrix(tuple(sorted(bufs)), (sid,))
+                    return True
+                except (UnsolvableError, ValueError):
+                    return False
+
+            if not solvable() and missing:
+                # lint: allow-executor — lazy ex.map + early break once the
+                # solver is satisfied needs a scoped pool whose exit joins
+                # the stragglers; bounded by the shard count (<= 13 tasks)
+                with ThreadPoolExecutor(max_workers=8) as ex:
+                    for i, arr in ex.map(fetch, missing):
+                        if arr is not None:
+                            bufs[i] = arr
+                        if solvable():
+                            break
+            rsp.set_attr(survivors=len(bufs))
         if not solvable():
             raise IOError(
                 f"ec volume {vid}: {len(bufs)} reachable shards "
@@ -828,47 +834,53 @@ class VolumeServer:
                 continue
         if plan is None:
             return None
-        bufs: dict[int, np.ndarray] = {}
-        need_remote: list[int] = []
-        for i in plan.reads:
-            f = ev.shard_files.get(i)
-            if f is not None and i not in exclude:
-                try:
-                    failpoint.fail("ec.shard.read",
-                                   ctx=f"{self.address}, shard={i},")
-                    data = f.read_at(soff, size)
-                    bufs[i] = np.frombuffer(
-                        data + b"\0" * (size - len(data)), np.uint8)
-                    continue
-                except OSError:
-                    pass  # fall through to a remote copy, if any
-            need_remote.append(i)
-        n_local = len(bufs)
+        # the survivor gather: local preads, then the plan's remote
+        # fetches; an unreachable planned survivor hands the read to the
+        # any-k backstop
+        with trace.span("volume.ec.survivor_read", child_only=True,
+                        plan="minimal") as rsp:
+            bufs: dict[int, np.ndarray] = {}
+            need_remote: list[int] = []
+            for i in plan.reads:
+                f = ev.shard_files.get(i)
+                if f is not None and i not in exclude:
+                    try:
+                        failpoint.fail("ec.shard.read",
+                                       ctx=f"{self.address}, shard={i},")
+                        data = f.read_at(soff, size)
+                        bufs[i] = np.frombuffer(
+                            data + b"\0" * (size - len(data)), np.uint8)
+                        continue
+                    except OSError:
+                        pass  # fall through to a remote copy, if any
+                need_remote.append(i)
+            n_local = len(bufs)
 
-        def fetch_planned(i):
-            for addr in locs.get(i, []):
-                if addr == self.address:
-                    continue
-                try:
-                    return i, np.frombuffer(self._remote_shard_read(
-                        addr, vid, i, soff, size), np.uint8)
-                except grpc.RpcError:
-                    continue
-            return i, None
+            def fetch_planned(i):
+                for addr in locs.get(i, []):
+                    if addr == self.address:
+                        continue
+                    try:
+                        return i, np.frombuffer(self._remote_shard_read(
+                            addr, vid, i, soff, size), np.uint8)
+                    except grpc.RpcError:
+                        continue
+                return i, None
 
-        if need_remote:
-            # gather the plan's remote survivors CONCURRENTLY — the
-            # minimal-read path must pay max(RTT), not sum(RTT), or it
-            # loses to the parallel any-k backstop it exists to beat
-            # lint: allow-executor — scoped pool: the all-or-nothing
-            # early return (None -> generic path) must join every fetch
-            with ThreadPoolExecutor(
-                    max_workers=min(8, len(need_remote))) as ex:
-                for i, arr in ex.map(fetch_planned, need_remote):
-                    if arr is None:
-                        return None  # planned survivor unreachable:
-                        #              generic path takes over
-                    bufs[i] = arr
+            if need_remote:
+                # gather the plan's remote survivors CONCURRENTLY — the
+                # minimal-read path must pay max(RTT), not sum(RTT), or it
+                # loses to the parallel any-k backstop it exists to beat
+                # lint: allow-executor — scoped pool: the all-or-nothing
+                # early return (None -> generic path) must join every fetch
+                with ThreadPoolExecutor(
+                        max_workers=min(8, len(need_remote))) as ex:
+                    for i, arr in ex.map(fetch_planned, need_remote):
+                        if arr is None:
+                            return None  # planned survivor unreachable:
+                            #              generic path takes over
+                        bufs[i] = arr
+            rsp.set_attr(survivors=len(bufs))
         n_remote = len(need_remote)
         pres = tuple(sorted(bufs))  # canonical order -> shared lane
         tsp.set_attr(survivors=len(pres), repairPlan=geo.code_name)
@@ -1397,14 +1409,20 @@ class VolumeGrpc:
         v, geo, coder, pace = self._generate_prologue(request, context)
         base = v.file_name()
         t0 = time.perf_counter()
-        try:
-            enc_stats = write_ec_files(base, coder, geo, pace=pace)
-        except QosUnavailable as e:
-            # starved mid-encode (budget reserved for higher classes or
-            # master lost): same abort surface as the admission probe —
-            # the shell's failure path rolls the replica back writable
-            context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
-        self._generate_epilogue(v, geo, base, t0, enc_stats)
+        # root of the encode's per-slab spans (ec.encode.*): a child of
+        # the caller's trace when it sent one, else a fresh root
+        with trace.span("volume.ec.encode", component="volume",
+                        server=self.srv.address, vid=v.id) as tsp:
+            try:
+                enc_stats = write_ec_files(base, coder, geo, pace=pace)
+            except QosUnavailable as e:
+                # starved mid-encode (budget reserved for higher classes
+                # or master lost): same abort surface as the admission
+                # probe — the shell's failure path rolls the replica
+                # back writable
+                context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
+            tsp.set_attr(slabs=enc_stats.batches, bytes=enc_stats.bytes)
+            self._generate_epilogue(v, geo, base, t0, enc_stats)
         return vs.VolumeEcShardsGenerateResponse()
 
     def VolumeEcShardsGenerateStreamed(self, request, context):
@@ -1715,15 +1733,21 @@ class VolumeGrpc:
         # the asked-for shards
         want = list(getattr(request, "shard_ids", [])) or None
         rstats: dict = {}
-        try:
-            rebuilt = rebuild_ec_files(base, coder, geo, pace=pace,
-                                       want=want, stats=rstats)
-        except QosUnavailable as e:
-            context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
         from ..pb import ec_geometry_pb2 as eg
         from ..storage.ec_volume import rebuild_ecx_file
 
-        rebuild_ecx_file(base)
+        # root of the rebuild's per-slab spans (ec.rebuild.*), as in
+        # VolumeEcShardsGenerate
+        with trace.span("volume.ec.rebuild", component="volume",
+                        server=self.srv.address,
+                        vid=request.volume_id) as tsp:
+            try:
+                rebuilt = rebuild_ec_files(base, coder, geo, pace=pace,
+                                           want=want, stats=rstats)
+            except QosUnavailable as e:
+                context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
+            tsp.set_attr(slabs=rstats.get("slabs", 0), shards=rebuilt)
+            rebuild_ecx_file(base)
         self.srv.scrubber.invalidate_ec_digest(request.volume_id,
                                                remove_manifest=True)
         self.srv.trigger_heartbeat()

@@ -27,7 +27,8 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..ops import dispatch
-from ..utils import numa
+from ..utils import numa, trace
+from ..utils.stats import EC_PIPELINE_SECONDS, EC_PIPELINE_SLABS
 from . import idx as idx_mod
 from . import needle_map, types
 from .ec_locate import Geometry
@@ -87,19 +88,57 @@ def _writer_thread_count(n_files: int) -> int:
     return min(n_files, max(2, 2 * (os.cpu_count() or 1)))
 
 
+_STATS_FIELD = {"read": "read_s", "submit": "dispatch_s",
+                "device_wait": "device_wait_s", "write": "write_s"}
+
+
+class _Phases:
+    """The per-slab phases of one encode or rebuild. Each phase is timed
+    once, on the thread where its work happens (`utils/trace.timed`), and
+    that one reading is the span `ec.<op>.<phase>`, a share of
+    `SeaweedFS_ec_pipeline_seconds{op, phase}` and the matching
+    `EncodeStats` field. The spans are children of the span active where
+    the pipeline started (`volume.ec.encode` / `volume.ec.rebuild`); the
+    reader and writer threads get it as their explicit parent, and
+    outside any span nothing is recorded."""
+
+    def __init__(self, op: str, stats: EncodeStats):
+        self.op = op
+        self.stats = stats
+        self.parent = trace.current_context()
+        self._lock = threading.Lock()
+        self._observe = {p: (lambda s, p=p: self._add(p, s))
+                         for p in _STATS_FIELD}
+
+    def _add(self, phase: str, seconds: float) -> None:
+        EC_PIPELINE_SECONDS.inc(seconds, op=self.op, phase=phase)
+        field_name = _STATS_FIELD[phase]
+        with self._lock:  # writes land from several writer threads
+            setattr(self.stats, field_name,
+                    getattr(self.stats, field_name) + seconds)
+
+    def timed(self, phase: str):
+        return trace.timed(f"ec.{self.op}.{phase}", parent=self.parent,
+                           child_only=True, observe=self._observe[phase])
+
+    def slab(self) -> None:
+        """One slab through the coordinator."""
+        self.stats.batches += 1
+        EC_PIPELINE_SLABS.inc(op=self.op)
+
+
 class _ShardWriters:
     """Shard files fanned out over writer threads; each shard maps to
     exactly one thread, so per-shard write order is preserved while
     independent files stream in parallel. Blocks of one slab release the
     recycled read buffer via a countdown once every data-shard row is on
-    disk."""
+    disk. Each row written is one `write` phase of `phases`."""
 
-    def __init__(self, files: dict[int, object], stats: EncodeStats,
+    def __init__(self, files: dict[int, object], phases: _Phases,
                  depth: int, n_threads: int | None = None,
                  numa_node: int | None = None):
         self._files = files
-        self._stats = stats
-        self._stats_lock = threading.Lock()
+        self._phases = phases
         self._numa_node = numa_node
         n = n_threads or _writer_thread_count(len(files))
         self._lanes: list[queue.Queue] = [
@@ -132,27 +171,27 @@ class _ShardWriters:
                 return
             shard_id, arr, nbytes, release = item
             if not self._errors:  # fail fast but keep draining queues
-                t0 = time.perf_counter()
-                try:
-                    from ..utils import failpoint
+                with self._phases.timed("write"):
+                    try:
+                        from ..utils import failpoint
 
-                    if failpoint.is_armed("ec.shard.write.corrupt"):
-                        # chaos hook (scrub plane): flip the first byte of
-                        # a targeted shard's slab as it lands on disk —
-                        # simulated shard bit rot the EC syndrome sweep
-                        # must find (ctx comma-terminates the id so
-                        # @shard=1, can't substring-hit shard 10)
-                        raw = bytes(memoryview(arr)[:nbytes])
-                        out = failpoint.corrupt(
-                            "ec.shard.write.corrupt", raw,
-                            ctx=f"shard={shard_id},")
-                        if out is not raw:
-                            arr = memoryview(out)
-                    self._files[shard_id].write(memoryview(arr)[:nbytes])
-                except BaseException as e:
-                    self._errors.append(e)
-                with self._stats_lock:
-                    self._stats.write_s += time.perf_counter() - t0
+                        if failpoint.is_armed("ec.shard.write.corrupt"):
+                            # chaos hook (scrub plane): flip the first
+                            # byte of a targeted shard's slab as it lands
+                            # on disk — simulated shard bit rot the EC
+                            # syndrome sweep must find (ctx comma-
+                            # terminates the id so @shard=1, can't
+                            # substring-hit shard 10)
+                            raw = bytes(memoryview(arr)[:nbytes])
+                            out = failpoint.corrupt(
+                                "ec.shard.write.corrupt", raw,
+                                ctx=f"shard={shard_id},")
+                            if out is not raw:
+                                arr = memoryview(out)
+                        self._files[shard_id].write(
+                            memoryview(arr)[:nbytes])
+                    except BaseException as e:
+                        self._errors.append(e)
             if release is not None:
                 release()
 
@@ -265,6 +304,10 @@ def generate_ec_files(
     are written regardless (they are the resume source and keep bytes
     bit-identical to the generate-then-copy path by construction).
 
+    Each slab's phases (read, submit, device_wait, write) are timed once
+    into the spans `ec.encode.<phase>`, `SeaweedFS_ec_pipeline_*` and the
+    returned EncodeStats (`_Phases`).
+
     `pace` (ISSUE 8, qos/priority.py BackgroundGovernor.pacer) is called
     with each slab's data-byte count before the slab's rows are routed
     to writers/sinks; it may block (waiting on cluster tokens) or raise
@@ -274,6 +317,7 @@ def generate_ec_files(
     dat_path = base_file_name + ".dat"
     dat_size = os.path.getsize(dat_path)
     stats = EncodeStats()
+    phases = _Phases("encode", stats)
     depth = max(1, pipeline_depth)
 
     outs = [open(geo.shard_file_name(base_file_name, i), "wb") for i in range(k + m)]
@@ -322,27 +366,25 @@ def generate_ec_files(
                         if stop.is_set() or buf.shape[1] < batch:
                             return
                         data = buf[:, :batch]
-                        t0 = time.perf_counter()
-                        # zero so rows fully past EOF stay zero; short reads
-                        # are zero-padded by _read_padded
-                        data[:] = 0
-                        for i in range(k):
-                            start = processed + block_size * i + b
-                            if start < dat_size:
-                                _read_padded(
-                                    f, start, min(batch, dat_size - start), data[i]
-                                )
-                        t1 = time.perf_counter()
-                        stats.read_s += t1 - t0
-                        parity_fut = encode(data)
-                        stats.dispatch_s += time.perf_counter() - t1
+                        with phases.timed("read"):
+                            # zero so rows fully past EOF stay zero; short
+                            # reads are zero-padded by _read_padded
+                            data[:] = 0
+                            for i in range(k):
+                                start = processed + block_size * i + b
+                                if start < dat_size:
+                                    _read_padded(
+                                        f, start, min(batch, dat_size - start),
+                                        data[i])
+                        with phases.timed("submit"):
+                            parity_fut = encode(data)
                         work_q.put((buf, data, parity_fut, batch))
                     processed += block_size * k
             work_q.put(None)
         except BaseException as e:  # surface in the coordinator/caller
             work_q.put(e)
 
-    writers = _ShardWriters(dict(enumerate(outs)), stats, depth,
+    writers = _ShardWriters(dict(enumerate(outs)), phases, depth,
                             numa_node=pipe_node)
     t = threading.Thread(target=reader, name="ec-encode-reader", daemon=True)
     t.start()
@@ -372,9 +414,8 @@ def generate_ec_files(
                     sinks.put(i, shard_off, data[i], nbytes)
             for i in range(k):
                 writers.put(i, data[i], nbytes, release)
-            t1 = time.perf_counter()
-            parity = np.asarray(parity_fut)  # blocks until device done
-            stats.device_wait_s += time.perf_counter() - t1
+            with phases.timed("device_wait"):
+                parity = np.asarray(parity_fut)  # blocks until device done
             for j in range(m):
                 # parity rows are views of one fresh array; numpy refcounts
                 # keep it alive until the last writer drops its view
@@ -382,7 +423,7 @@ def generate_ec_files(
                 if sinks is not None:
                     sinks.put(k + j, shard_off, parity[j], nbytes)
             shard_off += nbytes
-            stats.batches += 1
+            phases.slab()
             stats.bytes += k * nbytes
         writers.close()
         ok = True
@@ -487,7 +528,8 @@ def rebuild_ec_files(
     surplus). `want` restricts the rebuild to those shard ids (the
     genuinely-missing set cluster-wide — locally-absent shards that
     exist on peers need no rebuild here); `stats`, when given, receives
-    survivor_bytes_read / survivor_shards / geometry."""
+    survivor_bytes_read / survivor_shards / geometry / slabs. The slab
+    phases are timed as in generate_ec_files, under `ec.rebuild.*`."""
     total = geo.total_shards
     have = [os.path.exists(geo.shard_file_name(base_file_name, i)) for i in range(total)]
     missing = [i for i in range(total) if not have[i]]
@@ -547,48 +589,59 @@ def rebuild_ec_files(
     sched = dispatch.maybe_scheduler(coder) if use_stacked else None
 
     pipe_node = numa.next_node()  # shared by reader + writers (ISSUE 12)
+    phases = _Phases("rebuild", EncodeStats())
 
     def reader() -> None:
         numa.pin_thread(pipe_node)  # survivor reads stay node-local
         try:
+            # every survivor is as long as the first: the loop then stops
+            # at that length and times no read past the last slab
+            for i in reads:
+                size = os.fstat(ins[i].fileno()).st_size
+                if size != shard_size:
+                    raise IOError(f"ec shard size mismatch: expected "
+                                  f"{shard_size} got {size}")
             offset = 0
-            while not stop.is_set():
-                # survivors land in ONE contiguous [P, batch] buffer via
-                # readinto — the stacked reconstruct then runs a single
-                # column-permuted matmul with no device-side re-stack
-                stacked = np.empty((len(reads), batch_size),
-                                   dtype=np.uint8)
-                n = None
-                for j, i in enumerate(reads):
-                    ins[i].seek(offset)
-                    got = ins[i].readinto(memoryview(stacked[j]))
-                    if n is None:
-                        n = got
-                    elif got != n:
-                        raise IOError(
-                            f"ec shard size mismatch: expected {n} got {got}"
-                        )
+            while offset < shard_size and not stop.is_set():
+                with phases.timed("read"):
+                    # survivors land in ONE contiguous [P, batch] buffer
+                    # via readinto — the stacked reconstruct then runs a
+                    # single column-permuted matmul with no device-side
+                    # re-stack
+                    stacked = np.empty((len(reads), batch_size),
+                                       dtype=np.uint8)
+                    n = None
+                    for j, i in enumerate(reads):
+                        ins[i].seek(offset)
+                        got = ins[i].readinto(memoryview(stacked[j]))
+                        if n is None:
+                            n = got
+                        elif got != n:
+                            raise IOError(
+                                f"ec shard size mismatch: expected {n} "
+                                f"got {got}")
                 if not n:
                     break
-                if sched is not None:
-                    # fresh buffer each loop: the slab may reference it
-                    # without a defensive copy
-                    work_q.put(sched.reconstruct_stacked(
-                        reads_tuple, stacked[:, :n], want=want_tuple))
-                elif use_stacked:
-                    mids, rows = coder.reconstruct_stacked(
-                        reads_tuple, stacked[:, :n], want=want_tuple)
-                    work_q.put(dict(zip(mids, rows)))
-                else:
-                    bufs = {i: stacked[j, :n]
-                            for j, i in enumerate(reads)}
-                    work_q.put(coder.reconstruct(bufs))
+                with phases.timed("submit"):
+                    if sched is not None:
+                        # fresh buffer each loop: the slab may reference
+                        # it without a defensive copy
+                        out = sched.reconstruct_stacked(
+                            reads_tuple, stacked[:, :n], want=want_tuple)
+                    elif use_stacked:
+                        mids, rows = coder.reconstruct_stacked(
+                            reads_tuple, stacked[:, :n], want=want_tuple)
+                        out = dict(zip(mids, rows))
+                    else:
+                        out = coder.reconstruct(
+                            {i: stacked[j, :n] for j, i in enumerate(reads)})
+                work_q.put(out)
                 offset += n
             work_q.put(None)
         except BaseException as e:
             work_q.put(e)
 
-    writers = _ShardWriters(outs, EncodeStats(), DEFAULT_PIPELINE_DEPTH,
+    writers = _ShardWriters(outs, phases, DEFAULT_PIPELINE_DEPTH,
                             numa_node=pipe_node)
     t = threading.Thread(target=reader, name="ec-rebuild-reader", daemon=True)
     t.start()
@@ -600,10 +653,13 @@ def rebuild_ec_files(
                 break
             if isinstance(rebuilt, BaseException):
                 raise rebuilt
-            if isinstance(rebuilt, dispatch.EcFuture):
-                mids, rows = rebuilt.result()
-                rebuilt = dict(zip(mids, rows))
-            slab_bytes = len(reads) * len(next(iter(rebuilt.values())))
+            with phases.timed("device_wait"):
+                if isinstance(rebuilt, dispatch.EcFuture):
+                    mids, rows = rebuilt.result()
+                    rebuilt = dict(zip(mids, rows))
+                host = {i: np.ascontiguousarray(
+                    np.asarray(rebuilt[i], dtype=np.uint8)) for i in missing}
+            slab_bytes = len(reads) * len(host[missing[0]])
             if pace is not None:
                 # repair-class budget draw: survivors read this slab —
                 # the minimal-read plan draws proportionally less
@@ -613,12 +669,13 @@ def rebuild_ec_files(
             if stats is not None:
                 stats["survivor_bytes_read"] += slab_bytes
             for i in missing:
-                row = np.ascontiguousarray(
-                    np.asarray(rebuilt[i], dtype=np.uint8))
-                writers.put(i, row, len(row))
+                writers.put(i, host[i], len(host[i]))
+            phases.slab()
         writers.close()
         ok = True
         EC_REPAIR_PLANS.inc(geometry=geo.code_name, kind="rebuild")
+        if stats is not None:
+            stats["slabs"] = phases.stats.batches
     finally:
         stop.set()
         if not ok:

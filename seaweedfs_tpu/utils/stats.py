@@ -270,8 +270,6 @@ VOLUME_SERVER_NATIVE_REQUESTS = Gauge(
     "Requests served by the C++ data plane since start.")
 VOLUME_SERVER_EC_ENCODE_BYTES = Counter(
     "SeaweedFS_volumeServer_ec_encode_bytes", "Bytes erasure-encoded.")
-VOLUME_SERVER_EC_DEVICE_SECONDS = Counter(
-    "SeaweedFS_volumeServer_ec_device_seconds", "Device time in EC kernels.")
 FILER_REQUEST_HISTOGRAM = Histogram(
     "SeaweedFS_filer_request_seconds", "Filer request latency by type.")
 S3_REQUEST_HISTOGRAM = Histogram(
@@ -338,6 +336,23 @@ EC_RECON_CACHE_COUNTER = Counter(
     "SeaweedFS_ec_dispatch_recon_cache_ops",
     "Reconstructed-interval cache activity by result "
     "(hit/miss/put/invalidate/evict).")
+
+# -- EC file pipeline: the per-slab phases of the shard-file encode and
+#    rebuild (storage/ec_files.py), timed once with the spans
+#    ec.<op>.<phase> and EncodeStats --------------------------------------
+
+EC_PIPELINE_SECONDS = Counter(
+    "SeaweedFS_ec_pipeline_seconds",
+    "Seconds spent in each per-slab phase of the EC file pipeline, by op "
+    "(encode/rebuild) and phase: read (reader thread, rows from the "
+    ".dat or survivor shards), submit (reader thread, handing the slab "
+    "to the coder), device_wait (coordinator blocked until the slab's "
+    "rows are host bytes), write (shard-writer threads, summed over "
+    "threads).")
+EC_PIPELINE_SLABS = Counter(
+    "SeaweedFS_ec_pipeline_slabs",
+    "Slabs through the EC file pipeline by op (encode/rebuild); "
+    "seconds/slabs is the per-slab cost of a phase.")
 
 # -- compiled XOR-schedule codec plane (ISSUE 17): generator matrices
 #    lowered to cached bit-plane XOR programs for the host CPU path ------

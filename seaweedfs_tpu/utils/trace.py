@@ -24,10 +24,21 @@ trace's spans from every server it touched), and histogram exemplars
 in utils/stats.py (a p99 bucket in /metrics links to a retained trace
 id).
 
+One clock with the device: while a JAX profiler capture runs, every
+recorded span also writes itself into the capture as a TraceMe of its
+own name, opened and closed with the span on the thread that runs it,
+so the host plane of the `.xplane.pb` holds the program's spans beside
+the device ops. The profiler is found in `sys.modules` only: a process
+that never loaded JAX (master, filer) never imports it from here. With
+no capture running, a span pays one `is_enabled()` check for it.
+
 Cheap enough to leave on: a span is one perf_counter pair, one dict,
-and one deque append — no locks on the hot path beyond the store's
-(bench.py --trace-ab pins <= 2% median overhead on the smallfile A/B,
-BENCH_AB_ISSUE7.json). SWFS_TRACE=0 turns the whole plane into no-ops.
+and one deque append — no locks on the hot path beyond the store's.
+Measured on a TPU v5e host in the benchmark's archive cell (a 1 GiB
+volume encoded through VolumeEcShardsGenerate, ~1,750 spans a call):
+`encode_GBps` median 0.896 with spans on against 0.907 with
+SWFS_TRACE=0, three seeds each, a 1.2% cost. SWFS_TRACE=0 turns the
+whole plane into no-ops.
 
 Timing discipline (lint rule SWFS002): spans must never read the wall
 clock per-event — `time.time()` is not monotonic and a step (NTP slew,
@@ -40,6 +51,7 @@ from __future__ import annotations
 
 import os
 import random
+import sys
 import threading
 from collections import OrderedDict, deque
 
@@ -309,31 +321,90 @@ def _header_value(carrier) -> str | None:
     return None
 
 
+_annotation_cls = None
+
+
+def _profiler_annotation(name: str):
+    """An entered `jax.profiler.TraceAnnotation` named `name` while a
+    profiler capture runs, else None. Only where JAX is already loaded."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
 class _SpanCtx:
     """Slotted context manager around one Span — a plain class instead
     of @contextmanager because the generator machinery costs more than
     the span itself on the write hot path."""
 
-    __slots__ = ("sp", "activate", "_prev")
+    __slots__ = ("sp", "activate", "_prev", "_ann")
 
     def __init__(self, sp: Span, activate: bool):
         self.sp = sp
         self.activate = activate
         self._prev = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         if self.activate:
             self._prev = getattr(_tls, "span", None)
             _tls.span = self.sp
+        if self.sp.sampled:
+            self._ann = _profiler_annotation(self.sp.name)
         return self.sp
 
     def __exit__(self, et, ev, tb):
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
         if self.activate:
             _tls.span = self._prev
         sp = self.sp
         if ev is not None and not sp.error:
             sp.set_error(f"{et.__name__}: {ev}")
         sp.finish()
+        return False
+
+
+class _Timed:
+    """A span that always measures: after the block, `seconds` holds its
+    duration whether tracing is on or off. With a recording span it is
+    the span's own reading, so the span and whatever else is fed from
+    `seconds` (a counter, a stats field) share one measurement."""
+
+    __slots__ = ("_ctx", "_t0", "_observe", "seconds")
+
+    def __init__(self, ctx, observe):
+        self._ctx = ctx
+        self._observe = observe
+        self._t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Timed":
+        sp = self._ctx.__enter__()
+        self._t0 = sp._t0 if isinstance(sp, Span) else time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        ctx = self._ctx
+        ctx.__exit__(et, ev, tb)
+        if isinstance(ctx, _SpanCtx):
+            self.seconds = ctx.sp.duration_ms / 1000.0
+        else:
+            self.seconds = time.perf_counter() - self._t0
+        if self._observe is not None:
+            self._observe(self.seconds)
         return False
 
 
@@ -406,6 +477,14 @@ def span(name: str, *, carrier=None, parent=None, child_only: bool = False,
     if attrs:
         sp.attrs.update(attrs)
     return _SpanCtx(sp, activate)
+
+
+def timed(name: str, *, observe=None, **kw) -> _Timed:
+    """span(name, **kw) whose block is measured even when nothing is
+    recorded: `.seconds` after the block, and `observe(seconds)` called
+    with that one reading (counters and stats that time the same work
+    as the span)."""
+    return _Timed(span(name, **kw), observe)
 
 
 # -- the per-process span store --------------------------------------------

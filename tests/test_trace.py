@@ -7,8 +7,12 @@ S3 → filer → volume HTTP/gRPC → EC dispatch, W3C traceparent parsing
 
 from __future__ import annotations
 
+import glob
 import io
 import socket
+import subprocess
+import sys
+import threading
 import time
 
 import numpy as np
@@ -163,6 +167,105 @@ def test_histogram_exemplars_link_to_traces():
             stats._REGISTRY.remove(h)
 
 
+# -- one clock with the device: spans in the profiler's trace ---------------
+
+def _host_events(trace_dir, prefix):
+    """{name: [(host line index, duration ms)]} of the host-plane events
+    whose names start with `prefix` in the capture under trace_dir."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.setdefault(e.name, []).append(
+                        (li, e.duration_ns / 1e6))
+    return out
+
+
+def test_span_writes_itself_into_a_profiler_capture(tmp_path):
+    """While a capture runs, a recorded span is a host-plane event of its
+    own name, on the line of the thread that ran it, as long as the
+    span's own reading."""
+    import jax
+
+    assert trace._profiler_annotation("test.idle") is None  # no capture
+    done = {}
+
+    def worker():
+        with trace.span("test.xplane.worker") as sp:
+            with jax.profiler.TraceAnnotation("test.xplane.marker"):
+                time.sleep(0.02)
+        done["worker"] = sp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+        with trace.span("test.xplane.main") as main_sp:
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path, "test.xplane.")
+    (w_line, w_ms), = ev["test.xplane.worker"]
+    (marker_line, _), = ev["test.xplane.marker"]
+    (main_line, main_ms), = ev["test.xplane.main"]
+    assert w_line == marker_line != main_line
+    assert abs(w_ms - done["worker"].duration_ms) < 1.0
+    assert abs(main_ms - main_sp.duration_ms) < 1.0
+
+
+def test_disabled_plane_writes_nothing_into_a_capture(tmp_path,
+                                                      monkeypatch):
+    import jax
+
+    monkeypatch.setenv("SWFS_TRACE", "0")
+    trace.refresh_config()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("test.off.span"):
+                time.sleep(0.002)
+            with trace.timed("test.off.timed") as t:
+                time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        monkeypatch.undo()
+        trace.refresh_config()
+    assert t.seconds >= 0.002  # measured all the same
+    assert _host_events(tmp_path, "test.off.") == {}
+
+
+def test_importing_trace_does_not_import_jax():
+    code = ("import sys, seaweedfs_tpu.utils.trace as t\n"
+            "with t.span('x'):\n    pass\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_timed_feeds_one_reading_to_span_and_observer():
+    seen = []
+    with trace.span("timed-root") as root:
+        with trace.timed("timed-child", observe=seen.append) as t:
+            time.sleep(0.003)
+    child, = [s for s in trace.STORE.trace(root.trace_id)
+              if s["name"] == "timed-child"]
+    assert seen == [t.seconds] and t.seconds >= 0.003
+    assert child["durationMs"] == pytest.approx(t.seconds * 1e3, abs=1e-3)
+    # child_only outside any span: nothing recorded, still measured
+    before = trace.STORE.recorded
+    with trace.timed("timed-lonely", child_only=True,
+                     observe=seen.append) as t2:
+        pass
+    assert trace.STORE.recorded == before and seen[-1] == t2.seconds
+
+
 # -- live cluster: propagation, degraded read, trace.dump, fuzz ------------
 
 @pytest.fixture(scope="module")
@@ -209,16 +312,11 @@ def _wait(cond, timeout=15.0, msg="condition"):
     raise AssertionError(f"timed out waiting for {msg}")
 
 
-def test_degraded_s3_read_produces_cross_server_trace(trace_stack):
-    """The acceptance path: one S3 GET of an EC'd object under 4-shard
-    loss returns an X-Trace-Id whose trace — gathered by `trace.dump`
-    from every server — covers s3 ingress → filer ladder → volume →
-    remote shard gRPC → dispatch-batched reconstruct, with queue-wait,
-    batch-factor and chip attributes present, and spans from the filer
-    plus BOTH volume servers."""
+@pytest.fixture(scope="module")
+def ec_object(trace_stack):
+    """An S3 object on an EC volume whose shards split across both
+    servers: 0..6 on the source, 7..13 on the other. -> (body, src, dst)"""
     master, volumes, fsrv, s3 = trace_stack
-
-    # --- stage an EC'd object whose shards split across both servers
     rng = np.random.default_rng(7)
     body = rng.integers(0, 256, size=3000, dtype=np.uint8).tobytes()
     requests.put(f"http://localhost:{s3.port}/tracebkt", timeout=10)
@@ -258,9 +356,11 @@ def test_degraded_s3_read_produces_cross_server_trace(trace_stack):
         timeout=30)
     _wait(lambda: len(master.topo.lookup_ec_shards(vid) or {}) == 14,
           msg="all 14 shards registered")
+    return body, src, dst
 
-    # the filer chunk cache was write-through-populated at PUT; the
-    # degraded read must hit the volume plane, where the loss lives
+
+def _degraded_s3_get(fsrv, s3, body):
+    """GET the EC'd object with shards 0..3 unreadable; -> its trace id."""
     saved_cache = fsrv.chunk_cache
     fsrv.chunk_cache = None
     lost = "|".join(f"shard={i}," for i in range(4))
@@ -272,10 +372,27 @@ def test_degraded_s3_read_produces_cross_server_trace(trace_stack):
             assert got.status_code == 200
             assert got.content == body
             assert fp.hits > 0, "shard loss never injected"
-        trace_id = got.headers.get("X-Trace-Id", "")
-        assert len(trace_id) == 32, got.headers
     finally:
         fsrv.chunk_cache = saved_cache
+    trace_id = got.headers.get("X-Trace-Id", "")
+    assert len(trace_id) == 32, got.headers
+    return trace_id
+
+
+def test_degraded_s3_read_produces_cross_server_trace(trace_stack,
+                                                      ec_object):
+    """The acceptance path: one S3 GET of an EC'd object under 4-shard
+    loss returns an X-Trace-Id whose trace — gathered by `trace.dump`
+    from every server — covers s3 ingress → filer ladder → volume →
+    remote shard gRPC → dispatch-batched reconstruct, with queue-wait,
+    batch-factor and chip attributes present, and spans from the filer
+    plus BOTH volume servers."""
+    master, volumes, fsrv, s3 = trace_stack
+    body, src, dst = ec_object
+
+    # the filer chunk cache was write-through-populated at PUT; the
+    # degraded read must hit the volume plane, where the loss lives
+    trace_id = _degraded_s3_get(fsrv, s3, body)
 
     # --- trace.dump gathers the trace from every server it touched
     env = CommandEnv(master.address, filer=fsrv.address)
@@ -323,6 +440,109 @@ def test_degraded_s3_read_produces_cross_server_trace(trace_stack):
     reads = [s for s in spans2 if s["name"] == "filer.chunk_read"]
     assert reads and all(s["attrs"].get("cache") in ("hit", "miss")
                          for s in reads)
+
+
+def _tree(spans):
+    by_id = {s["spanId"]: s for s in spans}
+    return lambda s: by_id.get(s["parentId"], {}).get("name")
+
+
+def test_degraded_read_splits_reconstruct_into_gather_and_wait(
+        trace_stack, ec_object):
+    """Under volume.ec.reconstruct, the survivor gather (local preads and
+    remote fetches) and the wait on the dispatch scheduler are spans of
+    their own; the batch that ran the slab is a span under the wait."""
+    master, volumes, fsrv, s3 = trace_stack
+    body, src, dst = ec_object
+    trace_id = _degraded_s3_get(fsrv, s3, body)
+    spans = trace.STORE.trace(trace_id)
+    parent = _tree(spans)
+    recon = [s for s in spans if s["name"] == "volume.ec.reconstruct"]
+    assert recon
+    gathers = [s for s in spans if s["name"] == "volume.ec.survivor_read"]
+    waits = [s for s in spans if s["name"] == "ec.dispatch.wait"]
+    assert len(waits) == len(recon)
+    # each reconstruct gathers once, or twice where a planned survivor
+    # fails and the any-k backstop gathers again
+    assert len(recon) <= len(gathers) <= 2 * len(recon)
+    assert {parent(s) for s in gathers + waits} == {"volume.ec.reconstruct"}
+    done = [g for g in gathers if "survivors" in g["attrs"]]
+    assert len(done) == len(recon)
+    assert all(g["attrs"]["survivors"] >= 10 for g in done)
+    batches = [s for s in spans if s["name"] == "ec.dispatch.reconstruct"]
+    assert batches and {parent(s) for s in batches} == {"ec.dispatch.wait"}
+    assert all(b["attrs"]["slabs"] >= 1 for b in batches)
+    # the wait covers the batch: submit until the rows are host bytes
+    assert max(w["durationMs"] for w in waits) >= \
+        min(b["durationMs"] for b in batches)
+    assert not any("dispatchWallMs" in s["attrs"] for s in spans)
+
+
+def test_ec_encode_and_rebuild_spans_and_pipeline_counters(trace_stack):
+    """One span per slab and phase under volume.ec.encode and
+    volume.ec.rebuild, as many slabs as the pipeline counted, and the
+    SeaweedFS_ec_pipeline_* counters grown by the same count."""
+    from seaweedfs_tpu.utils import stats
+
+    master, volumes, fsrv, s3 = trace_stack
+    rng = np.random.default_rng(11)
+    fids = [submit(master.address, rng.integers(
+        0, 256, size=4000, dtype=np.uint8).tobytes(),
+        collection="spanpipe")["fid"] for _ in range(4)]
+    vid = parse_file_id(fids[0]).volume_id
+    srv = next(v for v in volumes if v.store.has_volume(vid))
+    stub = rpc.volume_stub(rpc.grpc_address(srv.address))
+    stub.VolumeMarkReadonly(vs.VolumeMarkReadonlyRequest(volume_id=vid),
+                            timeout=30)
+
+    def counted():
+        return {(op, ph): stats.EC_PIPELINE_SECONDS.value(op=op, phase=ph)
+                for op in ("encode", "rebuild")
+                for ph in ("read", "submit", "device_wait", "write")}, {
+            op: stats.EC_PIPELINE_SLABS.value(op=op)
+            for op in ("encode", "rebuild")}
+
+    def root_and_children(name):
+        with trace.STORE._lock:
+            roots = [s for s in trace.STORE._ring if s.name == name
+                     and s.attrs.get("vid") == vid]
+        root, = roots
+        spans = trace.STORE.trace(root.trace_id)
+        kids = [s for s in spans if s["parentId"] == root.span_id]
+        by_name: dict = {}
+        for s in kids:
+            by_name[s["name"]] = by_name.get(s["name"], 0) + 1
+        return root, by_name
+
+    sec0, slabs0 = counted()
+    stub.VolumeEcShardsGenerate(vs.VolumeEcShardsGenerateRequest(
+        volume_id=vid, collection="spanpipe"), timeout=120)
+    sec1, slabs1 = counted()
+    root, kids = root_and_children("volume.ec.encode")
+    n = root.attrs["slabs"]
+    assert n > 1 and root.parent_id == ""  # no traceparent: a new root
+    assert slabs1["encode"] - slabs0["encode"] == n
+    assert kids["ec.encode.read"] == kids["ec.encode.submit"] == n
+    assert kids["ec.encode.device_wait"] == n
+    assert kids["ec.encode.write"] == 14 * n
+    assert all(sec1["encode", ph] > sec0["encode", ph]
+               for ph in ("read", "submit", "device_wait", "write"))
+
+    lost = [0, 5, 13]
+    stub.VolumeEcShardsDelete(vs.VolumeEcShardsDeleteRequest(
+        volume_id=vid, collection="spanpipe", shard_ids=lost), timeout=30)
+    resp = stub.VolumeEcShardsRebuild(vs.VolumeEcShardsRebuildRequest(
+        volume_id=vid, collection="spanpipe"), timeout=120)
+    assert sorted(resp.rebuilt_shard_ids) == lost
+    sec2, slabs2 = counted()
+    root, kids = root_and_children("volume.ec.rebuild")
+    n = root.attrs["slabs"]
+    assert n >= 1 and slabs2["rebuild"] - slabs1["rebuild"] == n
+    assert kids["ec.rebuild.read"] == kids["ec.rebuild.submit"] == n
+    assert kids["ec.rebuild.device_wait"] == n
+    assert kids["ec.rebuild.write"] == len(lost) * n
+    assert all(sec2["rebuild", ph] > sec1["rebuild", ph]
+               for ph in ("read", "submit", "device_wait", "write"))
 
 
 def test_malformed_traceparent_never_500s_always_reroots(trace_stack):
