@@ -27,8 +27,9 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..ops import dispatch
-from ..utils import numa, trace
-from ..utils.stats import EC_PIPELINE_SECONDS, EC_PIPELINE_SLABS
+from ..utils import fanout, numa, trace
+from ..utils.stats import (EC_PIPELINE_READ_STREAMS, EC_PIPELINE_SECONDS,
+                           EC_PIPELINE_SLABS)
 from . import idx as idx_mod
 from . import needle_map, types
 from .ec_locate import Geometry
@@ -88,6 +89,49 @@ def _writer_thread_count(n_files: int) -> int:
     return min(n_files, max(2, 2 * (os.cpu_count() or 1)))
 
 
+def _read_streams(rows: int) -> int:
+    """Concurrent positional reads that fill one slab: one per row, up
+    to one per CPU. A one-CPU host reads a slab's rows serially on the
+    reader thread, in row order. On a v5e host's 9p root, ten concurrent
+    4 MiB survivor reads took 2.8 ms against 16.6 ms for one stream into
+    the same buffers (PERF.md, section 6)."""
+    return max(1, min(rows, os.cpu_count() or 1))
+
+
+def _pread_full(fd: int, view: memoryview, offset: int) -> int:
+    """Positional read into `view` until it is full or the file ends;
+    returns the bytes read. A positional read may return short (9p
+    does), so it loops."""
+    got = 0
+    while got < len(view):
+        n = os.preadv(fd, [view[got:]], offset + got)
+        if n == 0:
+            break
+        got += n
+    return got
+
+
+def _pread_rows(reads: list, streams: int) -> list[int]:
+    """Fill every (fd, offset, view) of one slab and return the bytes
+    read into each. With one stream the reads run here, in order; else
+    they are cut into `streams` runs of consecutive reads on the shared
+    `ec_read` fan-out pool (one worker per CPU, shared by every
+    pipeline of the process, so not pinned to a pipeline's NUMA node),
+    and all of them have landed before this returns, a failed one
+    included."""
+    def run(part):
+        return [_pread_full(fd, view, off) for fd, off, view in part]
+
+    if streams <= 1:
+        return run(reads)
+    n = len(reads)
+    parts = [reads[i * n // streams:(i + 1) * n // streams]
+             for i in range(streams)]
+    got = fanout.run_all(run, parts, pool="ec_read",
+                         max_workers=os.cpu_count() or 1)
+    return [g for part in got for g in part]
+
+
 _STATS_FIELD = {"read": "read_s", "submit": "dispatch_s",
                 "device_wait": "device_wait_s", "write": "write_s"}
 
@@ -117,9 +161,17 @@ class _Phases:
             setattr(self.stats, field_name,
                     getattr(self.stats, field_name) + seconds)
 
-    def timed(self, phase: str):
+    def timed(self, phase: str, **attrs):
         return trace.timed(f"ec.{self.op}.{phase}", parent=self.parent,
-                           child_only=True, observe=self._observe[phase])
+                           child_only=True, observe=self._observe[phase],
+                           **attrs)
+
+    def read(self, streams: int):
+        """The `read` phase of one slab, filled by `streams` concurrent
+        reads: the span's attribute `streams` and
+        `SeaweedFS_ec_pipeline_read_streams{op}`."""
+        EC_PIPELINE_READ_STREAMS.inc(streams, op=self.op)
+        return self.timed("read", streams=streams)
 
     def slab(self) -> None:
         """One slab through the coordinator."""
@@ -254,16 +306,6 @@ def _pick_batch(block_size: int, requested: int) -> int:
     return max(b, 1)
 
 
-def _read_padded(f, offset: int, length: int, buf: np.ndarray) -> None:
-    """ReadAt with zero fill past EOF (ec_encoder.go:165-177)."""
-    f.seek(offset)
-    got = f.readinto(memoryview(buf)[:length])
-    if got is None:
-        got = 0
-    if got < length:
-        buf[got:length] = 0
-
-
 def generate_ec_files(
     base_file_name: str,
     coder,
@@ -281,13 +323,19 @@ def generate_ec_files(
 
     Pipeline, `pipeline_depth` slabs deep, with per-shard writer fan-out:
 
-      reader thread:   read slab -> launch encode (async JAX dispatch) ┐
+      read streams:    the slab's k rows, concurrent positional reads
+                        │ (all landed: the slab is whole)
+      reader thread:   launch encode (async JAX dispatch)             ┐
                                                                bounded queue
-      coordinator:     route data rows to writers -> block on parity   ┘
+      coordinator:     route data rows to writers -> block on parity  ┘
       14 shard writers: one thread per output file (independent streams;
                         queue-depth-14 writing measures ~3x one stream)
 
-    A recycled buffer pool caps host memory at ~(depth+2) slabs; a slab's
+    The read streams are `_read_streams(rows)` positional reads on the
+    process-wide `ec_read` fan-out pool (one worker per CPU, shared by
+    every pipeline); a one-CPU host reads the rows on the reader thread.
+    A slab is whole before it is submitted or handed to `sinks`. A
+    recycled buffer pool caps host memory at ~(depth+2) slabs; a slab's
     buffer is recycled only after every data-shard writer flushed its row
     (countdown). Multiple volumes encoding concurrently (BASELINE config
     #4) each run their own pipeline; their encode launches interleave on
@@ -356,6 +404,7 @@ def generate_ec_files(
         numa.pin_thread(pipe_node)  # reads + encode launches node-local
         try:
             with open(dat_path, "rb") as f:
+                fd = f.fileno()
                 processed = 0
                 for block_size in _row_schedule(geo, dat_size):
                     batch = _pick_batch(block_size, batch_size)
@@ -366,16 +415,22 @@ def generate_ec_files(
                         if stop.is_set() or buf.shape[1] < batch:
                             return
                         data = buf[:, :batch]
-                        with phases.timed("read"):
-                            # zero so rows fully past EOF stay zero; short
-                            # reads are zero-padded by _read_padded
-                            data[:] = 0
+                        # row i of the slab is .dat bytes from `start`;
+                        # ReadAt with zero fill past EOF
+                        # (ec_encoder.go:165-177)
+                        reads = []
+                        for i in range(k):
+                            start = processed + block_size * i + b
+                            if start < dat_size:
+                                reads.append((fd, start, memoryview(
+                                    data[i])[:min(batch, dat_size - start)]))
+                        streams = _read_streams(len(reads))
+                        with phases.read(streams):
+                            got = _pread_rows(reads, streams)
                             for i in range(k):
-                                start = processed + block_size * i + b
-                                if start < dat_size:
-                                    _read_padded(
-                                        f, start, min(batch, dat_size - start),
-                                        data[i])
+                                n = got[i] if i < len(got) else 0
+                                if n < batch:
+                                    data[i, n:] = 0
                         with phases.timed("submit"):
                             parity_fut = encode(data)
                         work_q.put((buf, data, parity_fut, batch))
@@ -529,7 +584,19 @@ def rebuild_ec_files(
     genuinely-missing set cluster-wide — locally-absent shards that
     exist on peers need no rebuild here); `stats`, when given, receives
     survivor_bytes_read / survivor_shards / geometry / slabs. The slab
-    phases are timed as in generate_ec_files, under `ec.rebuild.*`."""
+    phases are timed as in generate_ec_files, under `ec.rebuild.*`.
+
+    Pipeline, DEFAULT_PIPELINE_DEPTH slabs deep:
+
+      read streams:    P survivor rows at one offset, concurrent
+                        │ positional reads (all landed: the slab is whole)
+      reader thread:   submit the reconstruct                         ┐
+                                                               bounded queue
+      coordinator:     block on the rebuilt rows -> recycle the       ┘
+                       survivor buffer -> route rows to writers
+      writers:         one thread per missing shard file
+
+    Survivor buffers come from a pool of DEFAULT_PIPELINE_DEPTH + 2."""
     total = geo.total_shards
     have = [os.path.exists(geo.shard_file_name(base_file_name, i)) for i in range(total)]
     missing = [i for i in range(total) if not have[i]]
@@ -590,42 +657,48 @@ def rebuild_ec_files(
 
     pipe_node = numa.next_node()  # shared by reader + writers (ISSUE 12)
     phases = _Phases("rebuild", EncodeStats())
+    # survivors land in ONE contiguous [P, batch] buffer, so the stacked
+    # reconstruct runs a single column-permuted matmul with no
+    # device-side re-stack. The buffers are recycled as in
+    # generate_ec_files: a fresh 40 MiB buffer a slab spent most of its
+    # read in first-touch page faults. A buffer goes back to the pool
+    # only once its slab's rows are host bytes (end of device_wait): the
+    # scheduler references it without a copy until then.
+    cols = min(batch_size, shard_size)
+    free_q: queue.Queue = queue.Queue()
+    for _ in range(DEFAULT_PIPELINE_DEPTH + 2):
+        free_q.put(np.empty((len(reads), cols), dtype=np.uint8))
+    fds = [ins[i].fileno() for i in reads]
+    streams = _read_streams(len(reads))
 
     def reader() -> None:
         numa.pin_thread(pipe_node)  # survivor reads stay node-local
         try:
             # every survivor is as long as the first: the loop then stops
             # at that length and times no read past the last slab
-            for i in reads:
-                size = os.fstat(ins[i].fileno()).st_size
+            for fd in fds:
+                size = os.fstat(fd).st_size
                 if size != shard_size:
                     raise IOError(f"ec shard size mismatch: expected "
                                   f"{shard_size} got {size}")
             offset = 0
             while offset < shard_size and not stop.is_set():
-                with phases.timed("read"):
-                    # survivors land in ONE contiguous [P, batch] buffer
-                    # via readinto — the stacked reconstruct then runs a
-                    # single column-permuted matmul with no device-side
-                    # re-stack
-                    stacked = np.empty((len(reads), batch_size),
-                                       dtype=np.uint8)
-                    n = None
-                    for j, i in enumerate(reads):
-                        ins[i].seek(offset)
-                        got = ins[i].readinto(memoryview(stacked[j]))
-                        if n is None:
-                            n = got
-                        elif got != n:
-                            raise IOError(
-                                f"ec shard size mismatch: expected {n} "
-                                f"got {got}")
-                if not n:
-                    break
+                stacked = free_q.get()
+                if stacked is None or stop.is_set():
+                    return
+                n = min(cols, shard_size - offset)
+                with phases.read(streams):
+                    got = _pread_rows([(fd, offset, memoryview(row)[:n])
+                                       for fd, row in zip(fds, stacked)],
+                                      streams)
+                for g in got:
+                    if g != n:
+                        raise IOError(f"ec shard size mismatch: expected "
+                                      f"{n} got {g}")
                 with phases.timed("submit"):
                     if sched is not None:
-                        # fresh buffer each loop: the slab may reference
-                        # it without a defensive copy
+                        # the buffer is not recycled before the future
+                        # resolves: the slab references it without a copy
                         out = sched.reconstruct_stacked(
                             reads_tuple, stacked[:, :n], want=want_tuple)
                     elif use_stacked:
@@ -635,7 +708,7 @@ def rebuild_ec_files(
                     else:
                         out = coder.reconstruct(
                             {i: stacked[j, :n] for j, i in enumerate(reads)})
-                work_q.put(out)
+                work_q.put((stacked, out))
                 offset += n
             work_q.put(None)
         except BaseException as e:
@@ -648,17 +721,19 @@ def rebuild_ec_files(
     ok = False
     try:
         while True:
-            rebuilt = work_q.get()
-            if rebuilt is None:
+            item = work_q.get()
+            if item is None:
                 break
-            if isinstance(rebuilt, BaseException):
-                raise rebuilt
+            if isinstance(item, BaseException):
+                raise item
+            stacked, rebuilt = item
             with phases.timed("device_wait"):
                 if isinstance(rebuilt, dispatch.EcFuture):
                     mids, rows = rebuilt.result()
                     rebuilt = dict(zip(mids, rows))
                 host = {i: np.ascontiguousarray(
                     np.asarray(rebuilt[i], dtype=np.uint8)) for i in missing}
+            free_q.put(stacked)
             slab_bytes = len(reads) * len(host[missing[0]])
             if pace is not None:
                 # repair-class budget draw: survivors read this slab —
@@ -680,6 +755,7 @@ def rebuild_ec_files(
         stop.set()
         if not ok:
             writers.abort()
+        free_q.put(None)  # unblock a reader stuck on free_q.get()
         while t.is_alive():
             try:
                 work_q.get_nowait()

@@ -20,6 +20,8 @@ on — a circular wait. The tiers form a DAG instead:
         └─ blocks on volume handlers, which fan out on →
     "replicate" (replica sends)
         └─ blocks on replica handlers, which fan out on nothing
+    "ec_read"   (EC file pipeline slab fills, storage/ec_files.py: one
+                 worker per CPU) — local positional reads, blocks on nothing
 
 so saturation in one tier can never deadlock the tier below it. Tasks
 must never submit into their OWN pool (the classic shared-pool
@@ -59,10 +61,12 @@ def _shutdown() -> None:
         ex.shutdown(wait=False, cancel_futures=True)
 
 
-def executor(pool: str = "pipeline") -> ThreadPoolExecutor:
+def executor(pool: str = "pipeline",
+             max_workers: int | None = None) -> ThreadPoolExecutor:
     """The process-wide fan-out executor for `pool` (created on first
-    use). Pick the tier that matches what the task BLOCKS ON — see the
-    module docstring's dependency DAG."""
+    use, with `max_workers` threads, by default SWFS_FANOUT_THREADS).
+    Pick the tier that matches what the task BLOCKS ON — see the module
+    docstring's dependency DAG."""
     ex = _executors.get(pool)
     if ex is not None:
         return ex
@@ -72,7 +76,7 @@ def executor(pool: str = "pipeline") -> ThreadPoolExecutor:
             if not _executors:
                 atexit.register(_shutdown)
             ex = _executors[pool] = ThreadPoolExecutor(
-                max_workers=_threads(),
+                max_workers=max_workers or _threads(),
                 thread_name_prefix=f"swfs-fanout-{pool}")
         return ex
 
@@ -81,13 +85,15 @@ def submit(fn, *args, pool: str = "pipeline", **kwargs) -> Future:
     return executor(pool).submit(fn, *args, **kwargs)
 
 
-def run_all(fn, items, pool: str = "pipeline") -> list:
+def run_all(fn, items, pool: str = "pipeline",
+            max_workers: int | None = None) -> list:
     """Run `fn(item)` for every item concurrently; wait for ALL to
     settle, then raise the first failure (in item order). Waiting before
     raising matters for replication fan-out: an early raise would leave
     sends still holding the request body and the caller unable to tell
     which replicas actually received it."""
-    futs = [submit(fn, it, pool=pool) for it in items]
+    ex = executor(pool, max_workers)
+    futs = [ex.submit(fn, it) for it in items]
     first_err: BaseException | None = None
     results = []
     for f in futs:

@@ -353,6 +353,11 @@ EC_PIPELINE_SLABS = Counter(
     "SeaweedFS_ec_pipeline_slabs",
     "Slabs through the EC file pipeline by op (encode/rebuild); "
     "seconds/slabs is the per-slab cost of a phase.")
+EC_PIPELINE_READ_STREAMS = Counter(
+    "SeaweedFS_ec_pipeline_read_streams",
+    "Concurrent positional-read streams that filled the EC file "
+    "pipeline's slabs, summed over slabs, by op (encode/rebuild); "
+    "read_streams/slabs is the streams per slab.")
 
 # -- compiled XOR-schedule codec plane (ISSUE 17): generator matrices
 #    lowered to cached bit-plane XOR programs for the host CPU path ------
