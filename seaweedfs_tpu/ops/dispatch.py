@@ -70,6 +70,7 @@ from ..utils.stats import (
     EC_DISPATCH_ARENA_OPS,
     EC_DISPATCH_ARENA_POOLED,
     EC_DISPATCH_BATCHES,
+    EC_DISPATCH_LOCK_WAIT,
     EC_DISPATCH_SLABS,
     EC_DISPATCH_STACK_BYTES,
     EC_DISPATCH_STACK_SLABS,
@@ -792,24 +793,39 @@ class EcDispatchScheduler:
                 if now < deadline:
                     self._cv.wait(deadline - now)
                     continue
-                due = [k for k, l in self._lanes.items()
+                due = [(k, l[0].ctx) for k, l in self._lanes.items()
                        if l[0].t + self.window <= now]
             # elevator batching (same shape as the PR-2 group commit):
             # take the dispatch lock FIRST, re-pop after acquiring it —
             # every slab that arrived while the previous dispatch was in
             # flight rides this one instead of fragmenting into its own
-            for k in due:
-                self._flush_lane(k)
+            for k, ctx in due:
+                self._flush_lane(k, parent=ctx)
 
     def _demand_flush(self, key: tuple) -> None:
         self._flush_lane(key)
 
-    def _flush_lane(self, key: tuple) -> None:
-        with self._dispatch_mu:
+    def _flush_lane(self, key: tuple, parent=None) -> None:
+        """Dispatch the lane's slabs under `_dispatch_mu`. Every lane of
+        the coder, each chip's included, takes that one lock, so the
+        wait for it is the span `ec.dispatch.lock_wait` and
+        `SeaweedFS_ec_dispatch_lock_wait_seconds{lane, chip}`: a child
+        of `parent` (the flusher passes the lane's first slab's span),
+        else of the flushing thread's span."""
+        kind = "encode" if key[0] == "enc" else "reconstruct"
+        chip = self._lane_chip(key)
+        label = "-" if chip is None else str(chip)
+        with trace.timed("ec.dispatch.lock_wait", parent=parent,
+                         child_only=True, lane=kind, chip=label) as waited:
+            self._dispatch_mu.acquire()
+        EC_DISPATCH_LOCK_WAIT.inc(waited.seconds, lane=kind, chip=label)
+        try:
             with self._cv:
                 slabs = self._lanes.pop(key, None)
             if slabs:
-                self._dispatch(key, slabs)
+                self._dispatch(key, slabs, kind, label)
+        finally:
+            self._dispatch_mu.release()
 
     def flush(self) -> None:
         """Dispatch every pending lane now (tests; close)."""
@@ -821,10 +837,8 @@ class EcDispatchScheduler:
             for k in keys:
                 self._flush_lane(k)
 
-    def _dispatch(self, key: tuple, slabs: list[_Slab]) -> None:
-        kind = "encode" if key[0] == "enc" else "reconstruct"
-        chip = self._lane_chip(key)
-        label = "-" if chip is None else str(chip)
+    def _dispatch(self, key: tuple, slabs: list[_Slab], kind: str,
+                  label: str) -> None:
         now = time.perf_counter()
         device = self._chip_device(key)
         EC_DISPATCH_BATCHES.inc(lane=kind, chip=label,

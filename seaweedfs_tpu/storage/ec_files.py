@@ -28,8 +28,8 @@ import numpy as np
 
 from ..ops import dispatch
 from ..utils import fanout, numa, trace
-from ..utils.stats import (EC_PIPELINE_READ_STREAMS, EC_PIPELINE_SECONDS,
-                           EC_PIPELINE_SLABS)
+from ..utils.stats import (EC_PIPELINE_READ_QUEUE, EC_PIPELINE_READ_STREAMS,
+                           EC_PIPELINE_SECONDS, EC_PIPELINE_SLABS)
 from . import idx as idx_mod
 from . import needle_map, types
 from .ec_locate import Geometry
@@ -111,25 +111,39 @@ def _pread_full(fd: int, view: memoryview, offset: int) -> int:
     return got
 
 
-def _pread_rows(reads: list, streams: int) -> list[int]:
+def _pread_rows(reads: list, streams: int, phases: "_Phases") -> list[int]:
     """Fill every (fd, offset, view) of one slab and return the bytes
     read into each. With one stream the reads run here, in order; else
     they are cut into `streams` runs of consecutive reads on the shared
     `ec_read` fan-out pool (one worker per CPU, shared by every
     pipeline of the process, so not pinned to a pipeline's NUMA node),
     and all of them have landed before this returns, a failed one
-    included."""
-    def run(part):
+    included. The wait from handing the runs to the pool until the
+    first of them starts is the slab's `read_queue` phase: the worker
+    reads the clock, this thread closes the phase on that reading."""
+    def fill(part):
         return [_pread_full(fd, view, off) for fd, off, view in part]
 
     if streams <= 1:
-        return run(reads)
+        return fill(reads)
     n = len(reads)
     parts = [reads[i * n // streams:(i + 1) * n // streams]
              for i in range(streams)]
-    got = fanout.run_all(run, parts, pool="ec_read",
-                         max_workers=os.cpu_count() or 1)
-    return [g for part in got for g in part]
+    started: list[float] = []
+    first = threading.Event()
+
+    def run(part):
+        if not started:
+            started.append(time.perf_counter())
+            first.set()
+        return fill(part)
+
+    pool = fanout.executor("ec_read", os.cpu_count() or 1)
+    with phases.read_queue() as queued:
+        futs = [pool.submit(run, part) for part in parts]
+        first.wait()
+        queued.until(min(started))
+    return [g for part in fanout.gather(futs) for g in part]
 
 
 _STATS_FIELD = {"read": "read_s", "submit": "dispatch_s",
@@ -172,6 +186,17 @@ class _Phases:
         `SeaweedFS_ec_pipeline_read_streams{op}`."""
         EC_PIPELINE_READ_STREAMS.inc(streams, op=self.op)
         return self.timed("read", streams=streams)
+
+    def read_queue(self):
+        """Inside `read`, on the reader thread: the slab's reads queued
+        on the `ec_read` pool. Its span is a child of the slab's `read`
+        span; its seconds go to `SeaweedFS_ec_pipeline_read_queue_seconds
+        {op}` alone, since `read` already holds them."""
+        return trace.timed(f"ec.{self.op}.read_queue", child_only=True,
+                           observe=self._observe_queue)
+
+    def _observe_queue(self, seconds: float) -> None:
+        EC_PIPELINE_READ_QUEUE.inc(seconds, op=self.op)
 
     def slab(self) -> None:
         """One slab through the coordinator."""
@@ -426,7 +451,7 @@ def generate_ec_files(
                                     data[i])[:min(batch, dat_size - start)]))
                         streams = _read_streams(len(reads))
                         with phases.read(streams):
-                            got = _pread_rows(reads, streams)
+                            got = _pread_rows(reads, streams, phases)
                             for i in range(k):
                                 n = got[i] if i < len(got) else 0
                                 if n < batch:
@@ -690,7 +715,7 @@ def rebuild_ec_files(
                 with phases.read(streams):
                     got = _pread_rows([(fd, offset, memoryview(row)[:n])
                                        for fd, row in zip(fds, stacked)],
-                                      streams)
+                                      streams, phases)
                 for g in got:
                     if g != n:
                         raise IOError(f"ec shard size mismatch: expected "

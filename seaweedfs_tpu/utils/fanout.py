@@ -93,7 +93,12 @@ def run_all(fn, items, pool: str = "pipeline",
     sends still holding the request body and the caller unable to tell
     which replicas actually received it."""
     ex = executor(pool, max_workers)
-    futs = [ex.submit(fn, it) for it in items]
+    return gather([ex.submit(fn, it) for it in items])
+
+
+def gather(futs: list[Future]) -> list:
+    """The results of `futs` in order, once ALL have settled; then the
+    first failure, if any, is raised (see run_all)."""
     first_err: BaseException | None = None
     results = []
     for f in futs:

@@ -324,6 +324,12 @@ EC_DISPATCH_WINDOW_WAIT = Histogram(
     "SeaweedFS_ec_dispatch_window_wait_seconds",
     "Time a slab waited in the scheduler before its dispatch launched, "
     "by lane and chip.")
+EC_DISPATCH_LOCK_WAIT = Counter(
+    "SeaweedFS_ec_dispatch_lock_wait_seconds",
+    "Seconds flushes waited for the scheduler's dispatch lock before "
+    "their pack and coder call, by lane and chip: every lane of a "
+    "coder, each chip's included, dispatches under the one lock, from "
+    "the flusher thread or a demand-flushing submitter.")
 EC_DISPATCH_STACK_SLABS = Histogram(
     "SeaweedFS_ec_dispatch_stacked_slabs",
     "Slabs per stacked dispatch (the realized batch size).",
@@ -358,6 +364,12 @@ EC_PIPELINE_READ_STREAMS = Counter(
     "Concurrent positional-read streams that filled the EC file "
     "pipeline's slabs, summed over slabs, by op (encode/rebuild); "
     "read_streams/slabs is the streams per slab.")
+
+EC_PIPELINE_READ_QUEUE = Counter(
+    "SeaweedFS_ec_pipeline_read_queue_seconds",
+    "Seconds the EC file pipeline's slabs queued on the shared ec_read "
+    "pool, by op (encode/rebuild): per slab, from handing its reads to "
+    "the pool until the first of them started; part of the read phase.")
 
 # -- compiled XOR-schedule codec plane (ISSUE 17): generator matrices
 #    lowered to cached bit-plane XOR programs for the host CPU path ------

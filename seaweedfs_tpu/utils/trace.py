@@ -224,8 +224,11 @@ class Span:
         threads, thread pools): pass to span(parent=...)."""
         return self.trace_id, self.span_id, self.sampled
 
-    def finish(self) -> None:
-        self.duration_ms = (time.perf_counter() - self._t0) * 1000.0
+    def finish(self, end: float | None = None) -> None:
+        """Close the span now, or at perf_counter time `end`."""
+        if end is None:
+            end = time.perf_counter()
+        self.duration_ms = (end - self._t0) * 1000.0
         if self.sampled:
             STORE.record(self)
 
@@ -347,13 +350,14 @@ class _SpanCtx:
     of @contextmanager because the generator machinery costs more than
     the span itself on the write hot path."""
 
-    __slots__ = ("sp", "activate", "_prev", "_ann")
+    __slots__ = ("sp", "activate", "_prev", "_ann", "end")
 
     def __init__(self, sp: Span, activate: bool):
         self.sp = sp
         self.activate = activate
         self._prev = None
         self._ann = None
+        self.end = None
 
     def __enter__(self) -> Span:
         if self.activate:
@@ -373,7 +377,7 @@ class _SpanCtx:
         sp = self.sp
         if ev is not None and not sp.error:
             sp.set_error(f"{et.__name__}: {ev}")
-        sp.finish()
+        sp.finish(self.end)
         return False
 
 
@@ -381,15 +385,24 @@ class _Timed:
     """A span that always measures: after the block, `seconds` holds its
     duration whether tracing is on or off. With a recording span it is
     the span's own reading, so the span and whatever else is fed from
-    `seconds` (a counter, a stats field) share one measurement."""
+    `seconds` (a counter, a stats field) share one measurement.
 
-    __slots__ = ("_ctx", "_t0", "_observe", "seconds")
+    `until(t)` ends the reading at perf_counter time `t` instead of at
+    the block's exit: for work whose end is seen on another thread,
+    whose clock read the block waited for. In a profiler capture the
+    span's event still closes with the block."""
+
+    __slots__ = ("_ctx", "_t0", "_observe", "_end", "seconds")
 
     def __init__(self, ctx, observe):
         self._ctx = ctx
         self._observe = observe
         self._t0 = 0.0
+        self._end = None
         self.seconds = 0.0
+
+    def until(self, t: float) -> None:
+        self._end = t
 
     def __enter__(self) -> "_Timed":
         sp = self._ctx.__enter__()
@@ -398,11 +411,15 @@ class _Timed:
 
     def __exit__(self, et, ev, tb):
         ctx = self._ctx
-        ctx.__exit__(et, ev, tb)
         if isinstance(ctx, _SpanCtx):
+            ctx.end = self._end
+            ctx.__exit__(et, ev, tb)
             self.seconds = ctx.sp.duration_ms / 1000.0
         else:
-            self.seconds = time.perf_counter() - self._t0
+            ctx.__exit__(et, ev, tb)
+            end = self._end
+            self.seconds = (time.perf_counter() if end is None
+                            else end) - self._t0
         if self._observe is not None:
             self._observe(self.seconds)
         return False

@@ -196,9 +196,9 @@ def test_rebuild_reuses_every_buffer_and_matches(
     fills: list[int] = []
     real_rows = ec_files._pread_rows
 
-    def rows(reads, streams):
+    def rows(reads, streams, phases):
         fills.append(id(reads[0][2].obj.base))  # the [P, batch] buffer
-        return real_rows(reads, streams)
+        return real_rows(reads, streams, phases)
 
     monkeypatch.setattr(ec_files, "_pread_rows", rows)
     if dispatch == "late":

@@ -24,7 +24,8 @@ import pytest
 from seaweedfs_tpu.models.coder import new_coder
 from seaweedfs_tpu.ops import dispatch
 from seaweedfs_tpu.ops.rs_cpu import RSCodecCPU
-from seaweedfs_tpu.parallel.mesh import ShardedCoder, device_count
+from seaweedfs_tpu.parallel.mesh import (ShardedCoder, device_count,
+                                         local_devices, make_mesh)
 from seaweedfs_tpu.storage import ec_files
 from seaweedfs_tpu.storage.ec_locate import Geometry
 from seaweedfs_tpu.utils import stats
@@ -41,10 +42,14 @@ def _clean_schedulers():
         "leaked ec-dispatch flusher thread"
 
 
-def _mesh_coder():
-    if device_count() < 2:
+def _mesh_coder(chips: int | None = None):
+    """A ShardedCoder over every device, or over the first `chips` (4:
+    the devices a v5e 2x2 host ships)."""
+    if device_count() < max(2, chips or 0):
         pytest.skip("needs the forced multi-device host platform")
-    return ShardedCoder(10, 4)
+    if chips is None:
+        return ShardedCoder(10, 4)
+    return ShardedCoder(10, 4, mesh=make_mesh(local_devices()[:chips]))
 
 
 # -- V-axis shard_map variants: bit-identity --------------------------------
@@ -132,11 +137,12 @@ def test_golden_shard_hashes_mesh_vsharded():
 # -- scheduler: per-chip lanes ----------------------------------------------
 
 
-def test_scheduler_per_chip_encode_bit_identity_and_spread():
+@pytest.mark.parametrize("chips", [4, 8])
+def test_scheduler_per_chip_encode_bit_identity_and_spread(chips):
     """Slabs submitted through the scheduler round-robin over per-chip
     lanes; every future's bytes match the rs_cpu oracle and every chip
     issued at least one batch."""
-    coder = _mesh_coder()
+    coder = _mesh_coder(chips)
     cpu = RSCodecCPU(10, 4)
     sched = dispatch.EcDispatchScheduler(coder, window=0.05)
     try:
@@ -181,11 +187,12 @@ def test_scheduler_vshard_env_gate_single_funnel():
         os.environ.pop("SWFS_EC_DISPATCH_VSHARD", None)
 
 
-def test_per_chip_lane_fairness_under_8_pipelines():
+@pytest.mark.parametrize("chips", [4, 8])
+def test_per_chip_lane_fairness_under_8_pipelines(chips):
     """8 concurrent encode pipelines (one thread each, as 8 volumes
     encoding at once): every chip's dispatch counter moves — the fleet
     saturates every chip's queue instead of funnelling through one."""
-    coder = _mesh_coder()
+    coder = _mesh_coder(chips)
     cpu = RSCodecCPU(10, 4)
     sched = dispatch.EcDispatchScheduler(coder, window=0.02)
     try:

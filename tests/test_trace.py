@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import glob
 import io
+import os
 import socket
 import subprocess
 import sys
@@ -264,6 +265,162 @@ def test_timed_feeds_one_reading_to_span_and_observer():
                      observe=seen.append) as t2:
         pass
     assert trace.STORE.recorded == before and seen[-1] == t2.seconds
+
+
+def _ring_spans(trace_id: str) -> list:
+    """The Span objects of one trace still in the store's ring."""
+    with trace.STORE._lock:
+        return [s for s in trace.STORE._ring if s.trace_id == trace_id]
+
+
+def test_dispatch_lock_wait_span_once_per_flush_on_the_flushing_thread(
+        tmp_path):
+    """Each flush records one `ec.dispatch.lock_wait` span on the thread
+    that flushes, closed before that flush's `ec.dispatch.encode` span
+    opens: a consumer's demand flush on the consumer's thread (a child
+    of its span), a window flush on the flusher thread (a child of the
+    first slab's span). `SeaweedFS_ec_dispatch_lock_wait_seconds` grows
+    by the spans' readings (other schedulers of the process may add to
+    it)."""
+    import jax
+
+    from seaweedfs_tpu.models.coder import new_coder
+    from seaweedfs_tpu.ops import dispatch
+    from seaweedfs_tpu.utils import stats
+
+    coder = new_coder(10, 4, "cpu")
+    rng = np.random.default_rng(5)
+
+    def slab():
+        return rng.integers(0, 256, (10, 64), dtype=np.uint8)
+
+    waited0 = stats.EC_DISPATCH_LOCK_WAIT.value(lane="encode", chip="-")
+    demand = dispatch.EcDispatchScheduler(coder, window=60.0)
+    windowed = dispatch.EcDispatchScheduler(coder, window=0.005)
+    roots = {}
+
+    def consumer():
+        with trace.span("test.lock.consumer") as sp:
+            futs = [demand.encode_parity(slab()) for _ in range(3)]
+            np.asarray(futs[0])  # demand flush, on this thread
+        roots["consumer"] = sp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t = threading.Thread(target=consumer)
+        t.start()
+        t.join()
+        with trace.span("test.lock.main") as sp:
+            fut = windowed.encode_parity(slab())
+            for _ in range(1000):
+                if fut.done():
+                    break
+                time.sleep(0.01)
+            assert fut.done()  # the flusher's window flush, no demand
+        roots["main"] = sp
+    finally:
+        jax.profiler.stop_trace()
+        demand.close()
+        windowed.close()
+
+    waited = 0.0
+    for root in roots.values():
+        spans = _ring_spans(root.trace_id)
+        lock, = [s for s in spans if s.name == "ec.dispatch.lock_wait"]
+        batch, = [s for s in spans if s.name == "ec.dispatch.encode"]
+        assert lock.parent_id == batch.parent_id == root.span_id
+        assert lock.attrs == {"lane": "encode", "chip": "-"}
+        assert lock._t0 + lock.duration_ms / 1e3 <= batch._t0
+        waited += lock.duration_ms / 1e3
+    assert stats.EC_DISPATCH_LOCK_WAIT.value(
+        lane="encode", chip="-") - waited0 >= waited - 1e-9 > 0
+    ev = _host_events(tmp_path, "test.lock.")
+    ev.update(_host_events(tmp_path, "ec.dispatch."))
+    (consumer_line, _), = ev["test.lock.consumer"]
+    (main_line, _), = ev["test.lock.main"]
+    lock_lines = sorted(li for li, _ in ev["ec.dispatch.lock_wait"])
+    assert lock_lines == sorted(li for li, _ in ev["ec.dispatch.encode"])
+    assert len(lock_lines) == 2 and consumer_line in lock_lines
+    assert main_line not in lock_lines  # the flusher thread's line
+
+
+def _encode_and_rebuild(tmp_path, lost: int = 7) -> dict:
+    """A 1,234-byte volume encoded and one shard rebuilt, each under a
+    span of its own, with four read streams a slab: {op: root span}."""
+    from seaweedfs_tpu.models.coder import new_coder
+    from seaweedfs_tpu.storage import ec_files
+
+    base = str(tmp_path / "q")
+    rng = np.random.default_rng(23)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, 1234, dtype=np.uint8).tobytes())
+    coder = new_coder(10, 4, "cpu")
+    roots = {}
+    with trace.span("test.queue.encode") as roots["encode"]:
+        ec_files.generate_ec_files(base, coder, TEST_GEO, batch_size=50)
+    os.remove(TEST_GEO.shard_file_name(base, lost))
+    with trace.span("test.queue.rebuild") as roots["rebuild"]:
+        ec_files.rebuild_ec_files(base, coder, TEST_GEO, batch_size=50)
+    return roots
+
+
+def test_read_queue_span_once_per_slab(tmp_path, monkeypatch):
+    """Every slab filled on the `ec_read` pool records one
+    `ec.<op>.read_queue` span, a child of its `ec.<op>.read` span and
+    inside it, for the encode and the rebuild; the counter
+    `SeaweedFS_ec_pipeline_read_queue_seconds{op}` grows by their
+    readings (other pipelines of the process may add to it)."""
+    from seaweedfs_tpu.utils import stats
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)
+    queued0 = {op: stats.EC_PIPELINE_READ_QUEUE.value(op=op)
+               for op in ("encode", "rebuild")}
+    slabs0 = {op: stats.EC_PIPELINE_SLABS.value(op=op)
+              for op in ("encode", "rebuild")}
+    for op, root in _encode_and_rebuild(tmp_path).items():
+        spans = _ring_spans(root.trace_id)
+        reads = {s.span_id: s for s in spans if s.name == f"ec.{op}.read"}
+        queues = [s for s in spans if s.name == f"ec.{op}.read_queue"]
+        slabs = stats.EC_PIPELINE_SLABS.value(op=op) - slabs0[op]
+        assert slabs >= 2 and len(reads) == len(queues) == slabs
+        assert sorted(q.parent_id for q in queues) == sorted(reads)
+        for q in queues:
+            read = reads[q.parent_id]
+            assert read._t0 <= q._t0
+            assert q._t0 + q.duration_ms / 1e3 <= \
+                read._t0 + read.duration_ms / 1e3
+        assert stats.EC_PIPELINE_READ_QUEUE.value(op=op) - queued0[op] \
+            >= sum(q.duration_ms for q in queues) / 1e3 - 1e-9
+
+
+def test_disabled_plane_records_no_lock_wait_or_read_queue(tmp_path,
+                                                           monkeypatch):
+    """With SWFS_TRACE=0 the lock wait and the read queue write nothing,
+    neither to the store nor into a capture; their counters are fed all
+    the same."""
+    import jax
+
+    from seaweedfs_tpu.utils import stats
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)
+    monkeypatch.setenv("SWFS_TRACE", "0")
+    trace.refresh_config()
+    waited0 = stats.EC_DISPATCH_LOCK_WAIT.value(lane="encode")
+    queued0 = stats.EC_PIPELINE_READ_QUEUE.value(op="encode")
+    recorded0 = trace.STORE.recorded
+    try:
+        jax.profiler.start_trace(str(tmp_path / "capture"))
+        try:
+            _encode_and_rebuild(tmp_path)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        monkeypatch.undo()
+        trace.refresh_config()
+    assert trace.STORE.recorded == recorded0
+    assert _host_events(tmp_path / "capture", "ec.") == {}
+    assert stats.EC_DISPATCH_LOCK_WAIT.value(lane="encode") > waited0
+    assert stats.EC_PIPELINE_READ_QUEUE.value(op="encode") > queued0
 
 
 # -- live cluster: propagation, degraded read, trace.dump, fuzz ------------
