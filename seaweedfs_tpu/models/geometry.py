@@ -310,7 +310,8 @@ def _repair_matrix_cached(geom: CodeGeometry, present: tuple[int, ...],
 # while later arrivals wait on the condition instead of duplicating the
 # (CSE-heavy) compile; rank 820 slots between the reconstruct-plan cache
 # (810) and the buffer pool (850) in the witness lock order, above
-# dispatch.mu (100) which holds it during lane selection.
+# dispatch.mu (100) and the per-chip dispatch locks (101 up), which a
+# flush holds during lane selection.
 
 _sched_cv = locks.wcondition("geometry.sched_cache", rank=820)
 _sched_cache: OrderedDict[tuple, object] = OrderedDict()

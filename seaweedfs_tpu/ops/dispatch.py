@@ -71,6 +71,7 @@ from ..utils.stats import (
     EC_DISPATCH_ARENA_POOLED,
     EC_DISPATCH_BATCHES,
     EC_DISPATCH_LOCK_WAIT,
+    EC_DISPATCH_OVERLAPPED,
     EC_DISPATCH_SLABS,
     EC_DISPATCH_STACK_BYTES,
     EC_DISPATCH_STACK_SLABS,
@@ -91,6 +92,10 @@ DEFAULT_REC_SETS = 128
 # submit restarts it) — idle schedulers self-clean instead of leaking a
 # thread per coder across tests
 _IDLE_EXIT_S = 1.0
+# witness rank of chip 0's submission lock; chip i's is one more per
+# index, so every chip lock ranks above dispatch.mu (100) and below
+# dispatch.lane_cv (200), and taking them in index order is legal
+_CHIP_RANK0 = 101
 
 
 def enabled() -> bool:
@@ -521,8 +526,11 @@ class EcDispatchScheduler:
     through one stacked launch per window — per-chip lanes keep the V
     (volume/slab) axis spread over every chip's own dispatch queue, so
     the chips fill in parallel (RapidRAID's pipelined distribution,
-    arXiv:1207.6744). SWFS_EC_DISPATCH_VSHARD=0 restores the single
-    funnel; single-device coders are untouched either way.
+    arXiv:1207.6744). Each chip lane is also SUBMITTED under its own
+    chip's lock, so the chips' packs and launches overlap on the host
+    too; only a launch that may span the mesh excludes them all.
+    SWFS_EC_DISPATCH_VSHARD=0 restores the single funnel; single-device
+    coders are untouched either way.
     """
 
     def __init__(self, coder, window: float | None = None,
@@ -538,26 +546,35 @@ class EcDispatchScheduler:
             os.environ.get("SWFS_EC_DISPATCH_MAX_SLABS",
                            str(DEFAULT_MAX_SLABS)))
         # lane state condition — witnessed (ISSUE 15): always acquired
-        # AFTER _dispatch_mu on the flush path, never before it
+        # AFTER the submission locks on the flush path, never before them
         self._cv = locks.wcondition("dispatch.lane_cv", rank=200)
         self._lanes: "OrderedDict[tuple, list[_Slab]]" = OrderedDict()
         # per-chip lane state — `_chips` resolves LAZILY on first submit:
         # asking a coder for its devices instantiates the backend (and
-        # takes the chip), which only EC work should do
+        # takes the chip), which only EC work should do. `_chip_mu` is
+        # published with it: one submission lock per placement device.
         self._chips: list | None = None
+        self._chip_mu: list = []
+        # flushes between their pop and the end of their coder call
+        # (guarded by _cv): what EC_DISPATCH_OVERLAPPED reads
+        self._submitting = 0
         self._enc_rr = itertools.count()
         self._rec_chips: "OrderedDict[tuple, int]" = OrderedDict()
         self._rec_rr = 0
         self._rec_max = int(os.environ.get("SWFS_EC_DISPATCH_REC_SETS",
                                            str(DEFAULT_REC_SETS)))
         self._thread: threading.Thread | None = None
-        # Serializes SUBMISSION into the coder (not completion — jax
-        # dispatch stays async, so batches still pipeline device-side).
-        # Without it, a demand flush on a consumer thread can race the
-        # flusher thread's window flush; on the multi-device CPU mesh two
-        # concurrently-submitted shard_map modules interleave their
-        # cross-module rendezvous and deadlock XLA (caught by
-        # tests/test_ec_pipeline.py under the 8-device test mesh).
+        # Submission locks: they serialize SUBMISSION into the coder, not
+        # completion (jax dispatch stays async, so batches still pipeline
+        # device-side). On the multi-device CPU mesh two concurrently
+        # submitted shard_map modules interleave their cross-module
+        # rendezvous and deadlock XLA (caught by tests/test_ec_pipeline.py
+        # under the 8-device test mesh). A chip-pinned flush launches
+        # single-device jits only, so it takes its chip's `_chip_mu` lock
+        # alone and the chips are fed side by side. A flush that may
+        # launch a multi-device module takes `_dispatch_mu` and then every
+        # chip lock, in index order (`_flush_lane`). On a single-device
+        # coder there are no chip locks: `_dispatch_mu` is its one lock.
         self._dispatch_mu = locks.wlock("dispatch.mu", rank=100)
         # host memory plane (ISSUE 12): lazily built so the env gate can
         # flip between A/B arms without rebuilding schedulers
@@ -633,14 +650,13 @@ class EcDispatchScheduler:
 
     # -- per-chip lane plumbing --------------------------------------------
 
-    def _chip_list(self) -> list:
-        """The coder's placement devices when per-chip lanes apply, else
-        []. Resolved once (instantiates the backend — acceptable here: a
-        submit IS device work, and a backend that fails raises to the
-        submitter); the env gate is re-read every call so an A/B can flip
-        V-axis sharding without rebuilding schedulers."""
-        if not vshard_enabled():
-            return []
+    def _placement(self) -> list:
+        """The coder's placement devices when it has per-chip entry
+        points and more than one device, else []. Resolved once, with a
+        submission lock per device (instantiates the backend — acceptable
+        here: a submit IS device work, and a backend that fails raises to
+        the submitter). Resolved whatever the env gate says, so the chip
+        locks exist before any flush of this scheduler and stay fixed."""
         chips = self._chips
         if chips is None:
             chips = []
@@ -650,8 +666,22 @@ class EcDispatchScheduler:
                 devs = fn()
                 if devs and len(devs) > 1:
                     chips = list(devs)
-            self._chips = chips
+            with self._cv:
+                if self._chips is None:  # first resolver publishes
+                    self._chip_mu = [
+                        locks.wlock(f"dispatch.chip{i}",
+                                    rank=_CHIP_RANK0 + i)
+                        for i in range(len(chips))]
+                    self._chips = chips
+                chips = self._chips
         return chips
+
+    def _chip_list(self) -> list:
+        """The placement devices when per-chip lanes apply, else []. The
+        env gate is re-read every call so an A/B can flip V-axis sharding
+        without rebuilding schedulers."""
+        chips = self._placement()
+        return chips if vshard_enabled() else []
 
     def _assign_rec_chip(self, key: tuple, n_chips: int) -> int:
         """Stable survivor-set -> chip placement, LRU-evicted: every slab
@@ -684,12 +714,21 @@ class EcDispatchScheduler:
         with self._cv:
             return self._rec_chips.get(key)
 
-    def _chip_device(self, key: tuple):
-        chips = self._chip_list()
-        idx = self._lane_chip(key)
-        if chips and idx is not None and idx < len(chips):
-            return chips[idx]
-        return None
+    def _mesh_wide(self, key: tuple, chip: int | None) -> bool:
+        """Whether a flush of this lane may launch a multi-device module:
+        every lane with no chip (on a mesh coder the V-stacked or
+        column-split launch), and a pinned reconstruct lane whose backlog
+        already fills the mesh (`_dispatch_reconstruct` may shard it).
+        Read before the flush pops the lane; the pop never widens what
+        was decided here (`_dispatch_reconstruct` only shards a batch
+        flushed mesh-wide)."""
+        if chip is None:
+            return True
+        if key[0] != "rec" or not hasattr(
+                self.coder, "reconstruct_stacked_vsharded"):
+            return False
+        with self._cv:
+            return len(self._lanes.get(key, ())) >= len(self._chip_mu)
 
     # -- submission --------------------------------------------------------
 
@@ -796,7 +835,7 @@ class EcDispatchScheduler:
                 due = [(k, l[0].ctx) for k, l in self._lanes.items()
                        if l[0].t + self.window <= now]
             # elevator batching (same shape as the PR-2 group commit):
-            # take the dispatch lock FIRST, re-pop after acquiring it —
+            # take the submission lock FIRST, re-pop after acquiring it —
             # every slab that arrived while the previous dispatch was in
             # flight rides this one instead of fragmenting into its own
             for k, ctx in due:
@@ -806,26 +845,48 @@ class EcDispatchScheduler:
         self._flush_lane(key)
 
     def _flush_lane(self, key: tuple, parent=None) -> None:
-        """Dispatch the lane's slabs under `_dispatch_mu`. Every lane of
-        the coder, each chip's included, takes that one lock, so the
-        wait for it is the span `ec.dispatch.lock_wait` and
+        """Dispatch the lane's slabs under the submission locks its
+        launch needs: a chip-pinned lane takes its chip's lock and
+        nothing else, so the chips' flushes overlap while each chip's
+        launches keep lane order; a flush that may launch a multi-device
+        module (`_mesh_wide`) takes `_dispatch_mu`, then every chip's
+        lock in index order, so nothing else of this scheduler is
+        submitted meanwhile. The wait for the lock or locks is the span
+        `ec.dispatch.lock_wait` and
         `SeaweedFS_ec_dispatch_lock_wait_seconds{lane, chip}`: a child
         of `parent` (the flusher passes the lane's first slab's span),
-        else of the flushing thread's span."""
+        else of the flushing thread's span. A batch whose submission
+        starts while another chip's is under way counts in
+        `SeaweedFS_ec_dispatch_overlapped_batches{lane, chip}`."""
         kind = "encode" if key[0] == "enc" else "reconstruct"
         chip = self._lane_chip(key)
         label = "-" if chip is None else str(chip)
+        mesh_wide = self._mesh_wide(key, chip)
+        held = ([self._dispatch_mu, *self._chip_mu] if mesh_wide
+                else [self._chip_mu[chip]])
         with trace.timed("ec.dispatch.lock_wait", parent=parent,
                          child_only=True, lane=kind, chip=label) as waited:
-            self._dispatch_mu.acquire()
+            for mu in held:
+                mu.acquire()
         EC_DISPATCH_LOCK_WAIT.inc(waited.seconds, lane=kind, chip=label)
         try:
             with self._cv:
                 slabs = self._lanes.pop(key, None)
-            if slabs:
-                self._dispatch(key, slabs, kind, label)
+                overlapped = self._submitting > 0
+                if slabs:
+                    self._submitting += 1
+            if not slabs:
+                return
+            if overlapped:
+                EC_DISPATCH_OVERLAPPED.inc(lane=kind, chip=label)
+            try:
+                self._dispatch(key, slabs, kind, chip, mesh_wide)
+            finally:
+                with self._cv:
+                    self._submitting -= 1
         finally:
-            self._dispatch_mu.release()
+            for mu in reversed(held):
+                mu.release()
 
     def flush(self) -> None:
         """Dispatch every pending lane now (tests; close)."""
@@ -838,9 +899,12 @@ class EcDispatchScheduler:
                 self._flush_lane(k)
 
     def _dispatch(self, key: tuple, slabs: list[_Slab], kind: str,
-                  label: str) -> None:
+                  chip: int | None, mesh_wide: bool) -> None:
         now = time.perf_counter()
-        device = self._chip_device(key)
+        # a pinned lane runs on its chip even if the env gate flipped
+        # since it was queued: it holds that chip's lock, not the mesh's
+        device = self._chips[chip] if chip is not None else None
+        label = "-" if chip is None else str(chip)
         EC_DISPATCH_BATCHES.inc(lane=kind, chip=label,
                                 reason=self._lane_reason(device))
         EC_DISPATCH_STACK_SLABS.observe(len(slabs), lane=kind)
@@ -853,14 +917,14 @@ class EcDispatchScheduler:
             s.fut.queue_wait_s = now - s.t
             s.fut.batch_slabs = len(slabs)
             s.fut.chip = label
-        # caller holds _dispatch_mu: coder submission is single-threaded
-        # (concurrent shard_map submissions deadlock XLA's cross-module
-        # rendezvous on the multi-device CPU mesh), and in-flight
-        # dispatch time turns into batching for the next elevator.
-        # Per-chip sub-dispatches are plain per-device jits — submission
-        # still serializes here (it's cheap), but EXECUTION proceeds on
-        # every chip's own queue concurrently. The batch span times the
-        # pack and the coder call on this (the flushing) thread.
+        # caller holds this lane's submission locks (`_flush_lane`): a
+        # chip lane's lock alone — per-chip sub-dispatches are plain
+        # per-device jits, so the chips' packs and launches overlap —
+        # or every lock for a launch that may span the mesh (concurrent
+        # shard_map submissions deadlock XLA's cross-module rendezvous
+        # on the multi-device CPU mesh). In-flight dispatch time turns
+        # into batching for the lane's next elevator. The batch span
+        # times the pack and the coder call on this (the flushing) thread.
         with trace.span(f"ec.dispatch.{kind}", parent=slabs[0].ctx,
                         child_only=True, slabs=len(slabs),
                         bytes=nbytes, chip=label):
@@ -868,7 +932,8 @@ class EcDispatchScheduler:
                 if key[0] == "enc":
                     self._dispatch_encode(slabs, device)
                 else:
-                    self._dispatch_reconstruct(key, slabs, device)
+                    self._dispatch_reconstruct(key, slabs, device,
+                                               mesh_wide)
             except BaseException as e:
                 for s in slabs:
                     if not s.fut.done():
@@ -970,7 +1035,7 @@ class EcDispatchScheduler:
         self._arena_release(buf, out)
 
     def _dispatch_reconstruct(self, key: tuple, slabs: list[_Slab],
-                              device=None) -> None:
+                              device, mesh_wide: bool) -> None:
         _, _geom, present_ids, data_only, want = key
         if not hasattr(self.coder, "reconstruct_stacked"):
             for s in slabs:  # exotic coder: per-slab dict reconstruct
@@ -980,13 +1045,15 @@ class EcDispatchScheduler:
             return
         chips = self._chip_list()
         fn_v = getattr(self.coder, "reconstruct_stacked_vsharded", None)
-        if (fn_v is not None and chips and len(slabs) >= len(chips)
+        if (mesh_wide and fn_v is not None and chips
+                and len(slabs) >= len(chips)
                 and len({s.width for s in slabs}) == 1):
             # a BIG uniform batch (a rebuild pipeline's demand-flushed
             # backlog) outgrows its single assigned chip: shard the V
             # axis over the whole mesh instead, so a lone rebuild uses
             # every chip (small serving micro-batches keep the
-            # survivor-set chip placement below). `want` (the rebuild's
+            # survivor-set chip placement below, and so does a batch
+            # flushed under its chip's lock alone). `want` (the rebuild's
             # minimal-read form) rides through — it must not demote the
             # rebuild workload to a single chip. Uniform widths mean the
             # arena pack memsets NOTHING (every byte is payload).

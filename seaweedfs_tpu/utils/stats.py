@@ -326,10 +326,18 @@ EC_DISPATCH_WINDOW_WAIT = Histogram(
     "by lane and chip.")
 EC_DISPATCH_LOCK_WAIT = Counter(
     "SeaweedFS_ec_dispatch_lock_wait_seconds",
-    "Seconds flushes waited for the scheduler's dispatch lock before "
-    "their pack and coder call, by lane and chip: every lane of a "
-    "coder, each chip's included, dispatches under the one lock, from "
-    "the flusher thread or a demand-flushing submitter.")
+    "Seconds flushes waited for the scheduler's submission locks before "
+    "their pack and coder call, by lane and chip: a chip-pinned lane "
+    "waits for its chip's lock, a launch that may span the mesh (or any "
+    "lane of a single-device coder) for the scheduler's whole-coder "
+    "lock and every chip's; from the flusher thread or a "
+    "demand-flushing submitter.")
+EC_DISPATCH_OVERLAPPED = Counter(
+    "SeaweedFS_ec_dispatch_overlapped_batches",
+    "Batches whose submission (pack and coder call) started while "
+    "another chip's submission of the same scheduler was still under "
+    "way, by lane and chip; over SeaweedFS_ec_dispatch_batches, the "
+    "share of batches the per-chip submission locks let overlap.")
 EC_DISPATCH_STACK_SLABS = Histogram(
     "SeaweedFS_ec_dispatch_stacked_slabs",
     "Slabs per stacked dispatch (the realized batch size).",
