@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import collections
 import functools
-import os
 import threading
 
 import jax
@@ -120,9 +119,9 @@ def decode_matrix_cached(
     return dec, tuple(used)
 
 
-# Derived kernel operands (bit-form / xor-coefficient form), cached by the
-# compact identity of the matrix — ("parity", k, m) or ("dec", k, m, present)
-# — so the hot path never re-serializes or re-expands matrix contents.
+# Derived bit-form kernel operands, cached by the compact identity of the
+# matrix — ("parity", k, m) or ("dec", k, m, present) — so the hot path
+# never re-serializes or re-expands matrix contents.
 # LRU eviction: hot keys (the encode parity matrix) survive survivor-set churn.
 _DERIVED_MAX = 4096
 _derived_forms: "collections.OrderedDict[tuple, np.ndarray]" = (
@@ -131,25 +130,17 @@ _derived_forms: "collections.OrderedDict[tuple, np.ndarray]" = (
 _derived_lock = threading.Lock()
 
 
-def _derived(form: str, key: tuple, matrix: np.ndarray) -> np.ndarray:
-    if form not in ("bits", "xor"):
-        raise ValueError(f"derived form must be 'bits' or 'xor', got {form!r}")
-    full = (form, *key)
+def _derived(key: tuple, matrix: np.ndarray) -> np.ndarray:
     with _derived_lock:
-        got = _derived_forms.get(full)
+        got = _derived_forms.get(key)
         if got is not None:
-            _derived_forms.move_to_end(full)
+            _derived_forms.move_to_end(key)
             return got
-    if form == "bits":
-        got = gf_matrix_to_bits(matrix)
-    else:
-        from .rs_xor import xor_coefficients
-
-        got = xor_coefficients(matrix)
+    got = gf_matrix_to_bits(matrix)
     with _derived_lock:
         while len(_derived_forms) >= _DERIVED_MAX:
             _derived_forms.popitem(last=False)
-        _derived_forms[full] = got
+        _derived_forms[key] = got
     return got
 
 
@@ -165,10 +156,10 @@ _device_ops: "collections.OrderedDict[tuple, jax.Array]" = (
 _device_ops_lock = threading.Lock()
 
 
-def _op_on_device(full_key: tuple, host_op: np.ndarray, device) -> jax.Array:
-    """The derived operand `host_op` (identified by `full_key`), committed
+def _op_on_device(op_key: tuple, host_op: np.ndarray, device) -> jax.Array:
+    """The derived operand `host_op` (identified by `op_key`), committed
     to `device` — cached, LRU-evicted."""
-    key = (full_key, device)
+    key = (op_key, device)
     with _device_ops_lock:
         got = _device_ops.get(key)
         if got is not None:
@@ -211,13 +202,13 @@ def fused_reconstruct_matrix(
 
 def fused_reconstruct_op(
     data_shards: int, parity_shards: int, present: tuple[int, ...],
-    missing: tuple[int, ...], form: str
+    missing: tuple[int, ...]
 ) -> tuple[np.ndarray, tuple[int, ...]]:
-    """Cached derived-form ("bits"/"xor") fused reconstruct operand."""
+    """Cached bit-form fused reconstruct operand."""
     fmat, used = fused_reconstruct_matrix(
         data_shards, parity_shards, present, missing)
-    op = _derived(form, ("fdec", data_shards, parity_shards, present,
-                         missing), fmat)
+    op = _derived(("fdec", data_shards, parity_shards, present, missing),
+                  fmat)
     return op, used
 
 
@@ -246,23 +237,22 @@ def fused_reconstruct_stacked_matrix(
 
 def fused_reconstruct_stacked_op(
     data_shards: int, parity_shards: int, present_ids: tuple[int, ...],
-    limit: int, form: str,
+    limit: int,
 ) -> tuple[tuple[int, ...], np.ndarray]:
-    """Cached derived-form of the stacked (column-permuted) operand."""
+    """Cached bit-form of the stacked (column-permuted) operand."""
     missing, pm = fused_reconstruct_stacked_matrix(
         data_shards, parity_shards, present_ids, limit)
     if not missing:
         return missing, pm
-    op = _derived(form, ("fdecs", data_shards, parity_shards,
-                         present_ids, missing), pm)
+    op = _derived(("fdecs", data_shards, parity_shards, present_ids,
+                   missing), pm)
     return missing, op
 
 
-def parity_matrix_op(data_shards: int, parity_shards: int,
-                     form: str) -> np.ndarray:
-    """Cached parity-matrix operand in "bits" or "xor" form."""
+def parity_matrix_op(data_shards: int, parity_shards: int) -> np.ndarray:
+    """Cached bit-form parity-matrix operand."""
     gp = gf256.parity_matrix(data_shards, parity_shards)
-    return _derived(form, ("parity", data_shards, parity_shards), gp)
+    return _derived(("parity", data_shards, parity_shards), gp)
 
 
 # -- geometry-general operands (ISSUE 11) ------------------------------------
@@ -277,9 +267,9 @@ def geom_parity_key(geom) -> tuple:
     return ("gparity", geom.name)
 
 
-def geom_parity_op(geom, form: str) -> np.ndarray:
-    """Derived-form parity operand for an arbitrary code geometry."""
-    return _derived(form, geom_parity_key(geom), geom.parity_matrix())
+def geom_parity_op(geom) -> np.ndarray:
+    """Bit-form parity operand for an arbitrary code geometry."""
+    return _derived(geom_parity_key(geom), geom.parity_matrix())
 
 
 @functools.lru_cache(maxsize=2048)
@@ -292,11 +282,9 @@ def geom_stacked_matrix(geom, present_ids: tuple[int, ...],
 
 
 def geom_stacked_op(geom, present_ids: tuple[int, ...],
-                    targets: tuple[int, ...],
-                    form: str) -> np.ndarray:
+                    targets: tuple[int, ...]) -> np.ndarray:
     pm = geom_stacked_matrix(geom, present_ids, targets)
-    op = _derived(form, ("gdecs", geom.name, present_ids, targets), pm)
-    return op
+    return _derived(("gdecs", geom.name, present_ids, targets), pm)
 
 
 def geom_targets_for(geom, present_ids: tuple[int, ...],
@@ -324,9 +312,10 @@ def _apply_matrix_jit(matrix_bits: jax.Array, data: jax.Array) -> jax.Array:
 @functools.partial(jax.jit, donate_argnums=(1,))
 def _apply_matrix_jit_donated(matrix_bits: jax.Array,
                               data: jax.Array) -> jax.Array:
-    """`_apply_matrix_jit` with the data buffer DONATED — see
-    rs_xor._matmul_xor_jit_donated for the contract. Used only on the
-    dispatch scheduler's committed-input (device-pinned) path."""
+    """`_apply_matrix_jit` with the data buffer DONATED: the caller's
+    committed copy is retired at execution (see `_donate_wanted`). Used
+    only on the dispatch scheduler's committed-input (device-pinned)
+    path."""
     return gf_matmul_bits(matrix_bits, data)
 
 
@@ -334,18 +323,15 @@ _donation_quiet = False
 
 
 def _donate_wanted() -> bool:
-    """Donation of committed flush inputs (ISSUE 12), gated
-    SWFS_EC_DISPATCH_DONATE (default on) and restricted to accelerator
-    backends: the CPU client zero-copies page-aligned host buffers into
-    device arrays, so a donated CPU "buffer" could be the dispatch
-    scheduler's arena memory itself — never hand XLA a buffer the arena
-    may recycle. XLA treats a donated input whose size matches no output
-    as a deallocate-eagerly hint (parity rows != data rows here), which
-    is exactly the point: retire the transfer buffer at execution."""
+    """Donation of committed flush inputs, restricted to
+    accelerator backends: the CPU client zero-copies page-aligned host
+    buffers into device arrays, so a donated CPU "buffer" could be the
+    dispatch scheduler's arena memory itself — never hand XLA a buffer
+    the arena may recycle. XLA treats a donated input whose size
+    matches no output as a deallocate-eagerly hint (parity rows != data
+    rows here), which is exactly the point: retire the transfer buffer
+    at execution."""
     global _donation_quiet
-    if os.environ.get("SWFS_EC_DISPATCH_DONATE", "1").lower() in (
-            "0", "false", "off"):
-        return False
     if jax.default_backend() == "cpu":
         return False
     if not _donation_quiet:
@@ -360,123 +346,27 @@ def _donate_wanted() -> bool:
     return True
 
 
-# Device kernel selection. Six formulations, all bit-identical:
-#   xor-pallas : packed-word mask*coef XOR scheme, hand-tiled (rs_xor)
-#   xor-xla    : same math, XLA-fused (any backend, any size)
-#   sel-pallas : xtime-select scheme — GF doubling chains + static
-#                XOR-selection by matrix bits (no bit extraction, ~no
-#                multiplies), hand-tiled
-#   sel-xla    : same, XLA-fused
-#   mxu-pallas : bitsliced GF(2) matmul in one VMEM tile (rs_pallas)
-#   mxu-xla    : bitsliced matmul, XLA-materialized (the original path)
-# SEAWEEDFS_TPU_KERNEL overrides; SEAWEEDFS_TPU_NO_PALLAS=1 (legacy) forces
-# the XLA formulations. bench.py calibrates and picks the winner.
-_KERNELS = ("xor-pallas", "xor-xla", "sel-pallas", "sel-xla",
-            "mxu-pallas", "mxu-xla")
-
-# Widest byte-column slab one dispatch of a word-packed formulation
-# (xor-*, sel-*, and the mesh coder's per-device "xor") takes. Their
-# bytes->int32-words repack (rs_xor._to_words / _to_bytes) is lane-padded
-# on the TPU: about 640 B of HBM per byte column whatever the row count,
-# so 32 MiB columns need 20 GB against a v5e's 16 GB. 8 MiB needs 5 GB
-# (tests/test_tpu_compile.py); wider work runs as several dispatches.
-# Columns are independent, so the bytes are identical.
-WORD_KERNEL_COLS = 8 << 20
-
-
-def _kernel_choice() -> str:
-    import os
-
-    choice = os.environ.get("SEAWEEDFS_TPU_KERNEL", "auto")
-    if choice != "auto":
-        if choice not in _KERNELS:
-            raise ValueError(
-                f"SEAWEEDFS_TPU_KERNEL={choice!r}: expected one of "
-                f"{_KERNELS} or 'auto'"
-            )
-        return choice
-    if os.environ.get("SEAWEEDFS_TPU_NO_PALLAS"):
-        return "sel-xla"
-    if jax.default_backend() == "tpu":
-        # the TPU default since round 4, from a kernel sweep of that
-        # round which has no ledger line; the benchmark's cells decide
-        # it (ROADMAP C1)
-        return "mxu-xla"
-    # off the TPU (Pallas compiles for the TPU only) sel-xla wins every
-    # case measured (CPU: 0.44 GB/s vs xor-xla 0.24, mxu-xla 0.06);
-    # decode matrices auto-route to xor-xla
-    return "sel-xla"
-
-
-def _dispatch_matmul(matrix: np.ndarray, data: jax.Array, out_rows: int,
-                     key: tuple = None, device=None) -> jax.Array:
-    """Padded GF matmul via the best backend for this platform/shape.
+def _dispatch_matmul(matrix: np.ndarray, data: jax.Array, key: tuple = None,
+                     device=None) -> jax.Array:
+    """Padded bitsliced GF matmul with a runtime matrix operand.
     `matrix` is the byte-form GF(256) matrix; `key` is its compact cache
     identity (defaults to hashing the contents). With `device`, the
-    computation is pinned to that chip (inputs committed there; derived
-    operands served from the device-resident LRU) — the per-chip lane
-    form used by the EC dispatch scheduler. Outputs are bit-identical
-    across paths (tests + bench assert it)."""
+    computation is pinned to that chip (inputs committed there; the bit
+    operand served from the device-resident LRU) — the per-chip lane
+    form used by the EC dispatch scheduler."""
     if key is None:
         key = ("raw", matrix.shape, matrix.tobytes())
     b = data.shape[1]
-    kind = _kernel_choice()
+    bits_np = _derived(key, matrix)
     donate = False
     if device is not None:
-        # pinned dispatches stay on the XLA formulations: placement is
-        # driven by committed inputs, which the hand-tiled pallas paths
-        # don't plumb — and bytes are identical across all formulations
-        kind = kind.replace("-pallas", "-xla")
         data = jax.device_put(data, device)
         # the committed copy is ours alone — donate it so XLA retires
         # the transfer buffer at execution (device residency, ISSUE 12)
         donate = _donate_wanted()
-    if kind.startswith("sel-") and key[0] in ("fdec", "fdecs", "gdecs"):
-        # sel kernels specialize on the static matrix; fused reconstruct
-        # matrices (one per survivor+missing set, up to C(n,k) of them)
-        # would recompile per failure pattern — route those to the
-        # runtime-operand xor form and keep sel for the one-per-geometry
-        # encode matrix
-        kind = kind.replace("sel-", "xor-")
-    if not kind.startswith("mxu-") and b > WORD_KERNEL_COLS:
-        return jnp.concatenate(
-            [_dispatch_matmul(matrix, data[:, i:i + WORD_KERNEL_COLS],
-                              out_rows, key=key, device=device)
-             for i in range(0, b, WORD_KERNEL_COLS)], axis=1)
-    if kind == "sel-pallas":
-        from .rs_xor import apply_matrix_sel_pallas
-
-        return apply_matrix_sel_pallas(matrix, data, token=key)
-    if kind == "sel-xla":
-        from .rs_xor import apply_matrix_sel
-
-        return apply_matrix_sel(matrix, _pad_bytes(data, b),
-                                token=key)[:, :b]
-    if kind == "xor-pallas":
-        from .rs_xor import apply_matrix_xor_pallas
-
-        coeffs = jnp.asarray(
-            _derived("xor", key, matrix).reshape(matrix.shape[0], -1)
-        )
-        return apply_matrix_xor_pallas(matrix, data, coeffs=coeffs)
-    if kind == "xor-xla":
-        from .rs_xor import _matmul_xor_jit, _matmul_xor_jit_donated
-
-        coeffs_np = _derived("xor", key, matrix)
-        coeffs = (_op_on_device(("xor", *key), coeffs_np, device)
-                  if device is not None else jnp.asarray(coeffs_np))
-        fn = _matmul_xor_jit_donated if donate else _matmul_xor_jit
-        return fn(coeffs, _pad_bytes(data, b))[:, :b]
-    bits_np = _derived("bits", key, matrix)
-    matrix_bits = (_op_on_device(("bits", *key), bits_np, device)
-                   if device is not None else jnp.asarray(bits_np))
-    if kind == "mxu-pallas":
-        from .rs_pallas import TILE_N, gf_matmul_bits_pallas
-
-        padded = (b + TILE_N - 1) // TILE_N * TILE_N
-        if padded != b:
-            data = jnp.pad(data, ((0, 0), (0, padded - b)))
-        return gf_matmul_bits_pallas(matrix_bits, data, out_rows)[:, :b]
+        matrix_bits = _op_on_device(key, bits_np, device)
+    else:
+        matrix_bits = jnp.asarray(bits_np)
     fn = _apply_matrix_jit_donated if donate else _apply_matrix_jit
     return fn(matrix_bits, _pad_bytes(data, b))[:, :b]
 
@@ -526,13 +416,12 @@ class RSCodecJax:
             # non-RS geometry: same kernels, its own generator matrix
             # (cache keys carry the geometry name, never (k, m))
             return _dispatch_matmul(
-                self.geometry.parity_matrix(), data, self.parity_shards,
+                self.geometry.parity_matrix(), data,
                 key=geom_parity_key(self.geometry), device=device)
-        if device is not None or _kernel_choice() != "mxu-xla":
+        if device is not None:
             gp = gf256.parity_matrix(self.data_shards, self.parity_shards)
             key = ("parity", self.data_shards, self.parity_shards)
-            return _dispatch_matmul(gp, data, self.parity_shards, key=key,
-                                    device=device)
+            return _dispatch_matmul(gp, data, key=key, device=device)
         out = _encode_jit(_pad_bytes(data, b), self.data_shards, self.parity_shards)
         return out[:, :b]
 
@@ -601,13 +490,13 @@ class RSCodecJax:
             key = ("gdecs", self.geometry.name, pres, missing)
             stacked = jnp.stack([jnp.asarray(present[i], jnp.uint8)
                                  for i in pres])
-            out = _dispatch_matmul(pm, stacked, len(missing), key=key)
+            out = _dispatch_matmul(pm, stacked, key=key)
             return {i: out[j] for j, i in enumerate(missing)}
         fmat, used = fused_reconstruct_matrix(
             self.data_shards, self.parity_shards, pres, missing)
         key = ("fdec", self.data_shards, self.parity_shards, pres, missing)
         stacked = jnp.stack([jnp.asarray(present[i], jnp.uint8) for i in used])
-        out = _dispatch_matmul(fmat, stacked, len(missing), key=key)
+        out = _dispatch_matmul(fmat, stacked, key=key)
         return {i: out[j] for j, i in enumerate(missing)}
 
     def reconstruct_stacked(
@@ -646,8 +535,7 @@ class RSCodecJax:
                 return (), jnp.zeros((0, stacked.shape[1]), jnp.uint8)
             pm = geom_stacked_matrix(self.geometry, present_ids, targets)
             key = ("gdecs", self.geometry.name, present_ids, targets)
-            out = _dispatch_matmul(pm, stacked, len(targets), key=key,
-                                   device=device)
+            out = _dispatch_matmul(pm, stacked, key=key, device=device)
             return targets, out
         missing, pm = fused_reconstruct_stacked_matrix(
             self.data_shards, self.parity_shards, present_ids, limit)
@@ -655,8 +543,7 @@ class RSCodecJax:
             return (), jnp.zeros((0, stacked.shape[1]), jnp.uint8)
         key = ("fdecs", self.data_shards, self.parity_shards,
                present_ids, missing)
-        out = _dispatch_matmul(pm, stacked, len(missing), key=key,
-                               device=device)
+        out = _dispatch_matmul(pm, stacked, key=key, device=device)
         return missing, out
 
     def verify(self, shards: np.ndarray | jax.Array) -> bool:

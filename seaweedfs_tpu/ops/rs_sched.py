@@ -82,9 +82,10 @@ OP_ZERO = 3   # reg[dst] = 0                 (degenerate all-zero rows)
 # slab. Numpy: an xtime is 4 whole-array passes (shift/mul/shift/xor)
 # and a dense table gather (table[c][row] fancy indexing) is byte-at-a-
 # time — measured ~24-30x an XOR pass at volume-slab sizes (the 4.5x
-# end-to-end speedup on RS(10,4) in BENCH_AB_ISSUE17.json follows from
-# it). Native: the vpshufb axpy is ~1.3 passes and the AVX2 xtime ~1.1,
-# so dense RS stays dense there and only (near-)pure-XOR matrices flip.
+# end-to-end speedup on RS(10,4) in CHANGES.md's compiled XOR-schedule
+# entry, a CPU measurement, follows from it). Native: the vpshufb axpy is ~1.3
+# passes and the AVX2 xtime ~1.1, so dense RS stays dense there and
+# only (near-)pure-XOR matrices flip.
 _COST = {
     "numpy": {"set": 1.0, "xor": 1.0, "xtime": 4.5, "zero": 0.5,
               "dense_one": 1.0, "dense_mul": 24.0, "dense_init": 0.5},

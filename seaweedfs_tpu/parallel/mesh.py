@@ -23,9 +23,9 @@ MXU matmul from ops/rs_jax.py runs per-device. Outputs keep the same
 sharding, so a host only pulls back the shard slabs it will write locally.
 
 This module is also the ONE sanctioned device-enumeration point:
-tools/lint.py rejects bare ``jax.devices()`` anywhere else (bench.py
-excepted) — device placement must go through the helpers here so mesh
-policy stays in one file.
+tools/lint.py rejects bare ``jax.devices()`` anywhere else — device
+placement must go through the helpers here so mesh policy stays in one
+file.
 """
 
 from __future__ import annotations
@@ -40,7 +40,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.rs_jax import (
-    WORD_KERNEL_COLS,
     fused_reconstruct_op,
     fused_reconstruct_stacked_op,
     geom_parity_op,
@@ -49,9 +48,16 @@ from ..ops.rs_jax import (
     gf_matmul_bits,
     parity_matrix_op,
 )
-from ..ops.rs_xor import gf_matmul_xor
 
 STRIPE_AXIS = "stripe"
+
+# Widest byte-column slab one device takes per mesh dispatch; wider work
+# runs as several dispatches, so the programs a mesh compiles stay at or
+# below this width. The v5e compile fuses the bitsliced program with no
+# scratch: about 20 B of HBM per column (data rows padded in, parity out),
+# 168 MB a chip at 8 MiB (compile rehearsal, tests/test_tpu_compile.py).
+# Columns are independent, so the bytes are identical.
+MESH_DISPATCH_COLS = 8 << 20
 
 # Serialized-submission guard (found by ISSUE 3's tier-1 CPU mesh): two
 # threads concurrently submitting multi-device shard_map modules interleave
@@ -80,7 +86,7 @@ def enable_compile_cache() -> str:
     every entry's key, so it never comes from a temp name, pid or clock.
     Every program is kept, however fast it compiled: a server compiles
     one per dispatch width and survivor set. Entry points call this
-    (command.main, chip_smoke.py, the bench's device child); tests do
+    (command.main, chip_smoke.py, benchmark/harness.py); tests do
     not."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
@@ -108,18 +114,14 @@ def _col_pad(b: int, n: int, quantum: int = 8) -> int:
     return (b + step - 1) // step * step
 
 
-def _per_device_fn(kernel: str):
-    return gf_matmul_xor if kernel == "xor" else gf_matmul_bits
-
-
 def _matrix_spec(matrix_op) -> P:
     return P(*(None,) * matrix_op.ndim)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _apply_sharded(matrix_op, data, mesh, axis, kernel):
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _apply_sharded(matrix_op, data, mesh, axis):
     fn = jax.shard_map(
-        lambda m, d: _per_device_fn(kernel)(m, d),
+        gf_matmul_bits,
         mesh=mesh,
         in_specs=(_matrix_spec(matrix_op), P(None, axis)),
         out_specs=P(None, axis),
@@ -127,8 +129,8 @@ def _apply_sharded(matrix_op, data, mesh, axis, kernel):
     return fn(matrix_op, data)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _apply_stacked_vsharded(matrix_op, stack, mesh, axis, kernel):
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _apply_stacked_vsharded(matrix_op, stack, mesh, axis):
     """stack [V, R, B] with V sharded across the mesh -> [V, out, B].
 
     Each device holds whole slabs ([V/n, R, B] locally) and runs ONE
@@ -138,7 +140,7 @@ def _apply_stacked_vsharded(matrix_op, stack, mesh, axis, kernel):
     def local(m, s):
         v, r, b = s.shape
         wide = jnp.swapaxes(s, 0, 1).reshape(r, v * b)
-        out = _per_device_fn(kernel)(m, wide)
+        out = gf_matmul_bits(m, wide)
         return jnp.swapaxes(out.reshape(out.shape[0], v, b), 0, 1)
 
     fn = jax.shard_map(
@@ -150,12 +152,12 @@ def _apply_stacked_vsharded(matrix_op, stack, mesh, axis, kernel):
     return fn(matrix_op, stack)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _parity_probe(matrix_op, shards, mesh, axis, data_shards, kernel):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _parity_probe(matrix_op, shards, mesh, axis, data_shards):
     """max over all bytes of (recomputed parity ^ stored parity); 0 iff clean.
     pmax over the mesh axis rides the ICI — cannot wrap, unlike a sum."""
     def local(m, x):
-        par = _per_device_fn(kernel)(m, x[:data_shards])
+        par = gf_matmul_bits(m, x[:data_shards])
         diff = jnp.max((par ^ x[data_shards:]).astype(jnp.int32))
         return jax.lax.pmax(diff, axis)
 
@@ -174,14 +176,11 @@ class ShardedCoder:
     """
 
     def __init__(self, data_shards: int = 10, parity_shards: int = 4,
-                 mesh: Mesh | None = None, kernel: str = "xor",
-                 geometry=None):
+                 mesh: Mesh | None = None, geometry=None):
         if data_shards <= 0 or parity_shards < 0:
             raise ValueError("bad geometry")
         if data_shards + parity_shards > 256:
             raise ValueError("at most 256 total shards in GF(256)")
-        if kernel not in ("xor", "bits"):
-            raise ValueError(f"kernel must be 'xor' or 'bits', got {kernel!r}")
         from ..models import geometry as geom_mod
 
         self.data_shards = data_shards
@@ -192,13 +191,10 @@ class ShardedCoder:
         self.mesh = mesh if mesh is not None else make_mesh()
         self.axis = self.mesh.axis_names[0]
         self._n = self.mesh.devices.size
-        # per-device formulation: "xor" (packed-word scheme, rs_xor — the
-        # faster one everywhere measured) or "bits" (bitsliced MXU matmul)
-        self.kernel = kernel
         self._parity_op = jnp.asarray(
-            parity_matrix_op(data_shards, parity_shards, kernel)
+            parity_matrix_op(data_shards, parity_shards)
             if self.geometry.is_rs
-            else geom_parity_op(self.geometry, kernel)
+            else geom_parity_op(self.geometry)
         )
 
     @property
@@ -223,39 +219,39 @@ class ShardedCoder:
 
     def _apply_cols(self, op, data) -> jax.Array:
         """op applied to [rows, B] with the columns split over the mesh,
-        at most WORD_KERNEL_COLS columns per device per dispatch (what
-        fits a v5e's HBM, rs_jax.WORD_KERNEL_COLS) -> [out, B]."""
+        at most MESH_DISPATCH_COLS columns per device per dispatch
+        -> [out, B]."""
         b = data.shape[1]
-        step = WORD_KERNEL_COLS * self._n
+        step = MESH_DISPATCH_COLS * self._n
         if b > step:
             return jnp.concatenate(
                 [self._apply_cols(op, data[:, i:i + step])
                  for i in range(0, b, step)], axis=1)
         arr, b = self._shard(data)
         with _SUBMIT_MU:
-            out = _apply_sharded(op, arr, self.mesh, self.axis, self.kernel)
+            out = _apply_sharded(op, arr, self.mesh, self.axis)
         return out[:, :b]
 
     def _apply_vstack(self, op, stack: np.ndarray) -> jax.Array:
         """op applied to every slab of [V, rows, B] with whole slabs
         sharded over the mesh -> [V, out, B]; one slab of at most
-        WORD_KERNEL_COLS columns per device per dispatch. With two or
+        MESH_DISPATCH_COLS columns per device per dispatch. With two or
         more slabs per device the v5e compile of the per-device
-        swapaxes/reshape takes minutes (252 s for 2 x 2 MiB, 554 s for
-        2 x 4 MiB, against 2.6 s for 1 x 4 MiB: compile rehearsal)."""
+        swapaxes/reshape took minutes (252 s for 2 x 2 MiB, 554 s for
+        2 x 4 MiB, against 2.6 s for 1 x 4 MiB: compile rehearsal of the
+        word-packed program this module ran before the bitsliced one)."""
         v, _, b = stack.shape
-        if b > WORD_KERNEL_COLS:
+        if b > MESH_DISPATCH_COLS:
             return jnp.concatenate(
-                [self._apply_vstack(op, stack[:, :, i:i + WORD_KERNEL_COLS])
-                 for i in range(0, b, WORD_KERNEL_COLS)], axis=2)
+                [self._apply_vstack(op, stack[:, :, i:i + MESH_DISPATCH_COLS])
+                 for i in range(0, b, MESH_DISPATCH_COLS)], axis=2)
         if v > self._n:
             return jnp.concatenate(
                 [self._apply_vstack(op, stack[i:i + self._n])
                  for i in range(0, v, self._n)], axis=0)
         arr, v0, b0 = self._vshard_put(stack)
         with _SUBMIT_MU:
-            out = _apply_stacked_vsharded(op, arr, self.mesh, self.axis,
-                                          self.kernel)
+            out = _apply_stacked_vsharded(op, arr, self.mesh, self.axis)
         return out[:v0, :, :b0]
 
     # -- codec surface -----------------------------------------------------
@@ -274,10 +270,11 @@ class ShardedCoder:
             "0", "false", "off")
 
     def _vshard_put(self, stack: np.ndarray) -> tuple[jax.Array, int, int]:
-        """Zero-pad V to a device multiple (and B to the kernel's word
-        quantum) and place slab-sharded. Zero slabs/columns encode and
-        reconstruct to zero bytes and are sliced away, the same argument
-        as the scheduler's ragged-tail column padding."""
+        """Zero-pad V to a device multiple (and B to 8 columns, the
+        quantum `_col_pad` uses) and place slab-sharded. Zero
+        slabs/columns encode and reconstruct to zero bytes and are sliced
+        away, the same argument as the scheduler's ragged-tail column
+        padding."""
         v, r, b = stack.shape
         pad_v = -(-v // self._n) * self._n
         pad_b = -(-b // 8) * 8
@@ -323,12 +320,11 @@ class ShardedCoder:
         if want is not None or not self.geometry.is_rs:
             missing = geom_targets_for(self.geometry, present_ids,
                                        data_only, want)
-            op_np = (geom_stacked_op(self.geometry, present_ids, missing,
-                                     self.kernel) if missing else None)
+            op_np = (geom_stacked_op(self.geometry, present_ids, missing)
+                     if missing else None)
         else:
             missing, op_np = fused_reconstruct_stacked_op(
-                self.data_shards, self.parity_shards, present_ids, limit,
-                self.kernel)
+                self.data_shards, self.parity_shards, present_ids, limit)
         if not missing:
             return (), jnp.zeros(
                 (stack.shape[0], 0, stack.shape[2]), jnp.uint8)
@@ -423,8 +419,7 @@ class ShardedCoder:
             return {}
         if not self.geometry.is_rs:
             pres = tuple(sorted(present.keys()))
-            op_np = geom_stacked_op(self.geometry, pres, missing,
-                                    self.kernel)
+            op_np = geom_stacked_op(self.geometry, pres, missing)
             used = pres
         else:
             # one fused [missing, k] matmul — parity rows are folded
@@ -433,7 +428,7 @@ class ShardedCoder:
             # encode dispatch
             op_np, used = fused_reconstruct_op(
                 self.data_shards, self.parity_shards,
-                tuple(sorted(present.keys())), missing, self.kernel)
+                tuple(sorted(present.keys())), missing)
         stacked = np.stack([np.asarray(present[i], np.uint8) for i in used])
         out_arr = self._apply_cols(jnp.asarray(op_np), stacked)
         return {i: out_arr[j] for j, i in enumerate(missing)}
@@ -451,12 +446,11 @@ class ShardedCoder:
         if want is not None or not self.geometry.is_rs:
             missing = geom_targets_for(self.geometry, present_ids,
                                        data_only, want)
-            op_np = (geom_stacked_op(self.geometry, present_ids, missing,
-                                     self.kernel) if missing else None)
+            op_np = (geom_stacked_op(self.geometry, present_ids, missing)
+                     if missing else None)
         else:
             missing, op_np = fused_reconstruct_stacked_op(
-                self.data_shards, self.parity_shards, present_ids, limit,
-                self.kernel)
+                self.data_shards, self.parity_shards, present_ids, limit)
         if not missing:
             return (), jnp.zeros((0, stacked.shape[1]), jnp.uint8)
         # hand the buffer to _shard untouched: a device-resident,
@@ -477,17 +471,15 @@ class ShardedCoder:
         comparison, SURVEY.md §5.3)."""
         shards = np.asarray(shards, dtype=np.uint8)
         assert shards.shape[0] == self.total_shards, shards.shape
-        step = WORD_KERNEL_COLS * self._n  # as in _apply_cols
+        step = MESH_DISPATCH_COLS * self._n  # as in _apply_cols
         if shards.shape[1] > step:
             return jnp.max(jnp.stack(
                 [self.parity_probe(shards[:, i:i + step])
                  for i in range(0, shards.shape[1], step)]))
         arr, _ = self._shard(shards)
         with _SUBMIT_MU:
-            return _parity_probe(
-                self._parity_op, arr, self.mesh, self.axis,
-                self.data_shards, self.kernel
-            )
+            return _parity_probe(self._parity_op, arr, self.mesh,
+                                 self.axis, self.data_shards)
 
     # kept as the historical name used by the dry-run driver
     parity_checksum = parity_probe

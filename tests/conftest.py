@@ -8,7 +8,7 @@ import os
 os.environ.setdefault("SWFS_LOCK_WITNESS", "1")
 
 # Tests run on a virtual 8-device CPU mesh; the chip is for chip_smoke.py
-# and bench.py. The CPU pin goes through jax.config before any backend is
+# and benchmark/run.py. The CPU pin goes through jax.config before any backend is
 # instantiated, so it holds whatever JAX_PLATFORMS says — and it is the
 # explicit pin the "tpu" coder accepts in place of a TPU
 # (models/coder.require_tpu).

@@ -2,12 +2,12 @@
 tiny 2-volume-server cluster under ~5s of the full mixed workload —
 zipfian S3 reads, small-file PUT flood, archival ec.encode churn and a
 degraded-read storm — asserting nonzero goodput per shape and a clean
-shutdown. The harness is the instrument every BENCH_CLUSTER_* A/B
-depends on; without this test it rots silently between bench runs.
+shutdown. The harness is the instrument every cluster A/B depends on;
+without this test it rots silently between runs.
 
 Runs the harness as a SUBPROCESS (its own JAX_PLATFORMS=cpu, its own
 port space, guaranteed teardown via its own signal handling) — the same
-way bench.py --cluster-qos drives it.
+way an operator drives it from the command line.
 """
 
 from __future__ import annotations

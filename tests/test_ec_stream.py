@@ -269,6 +269,26 @@ def test_copy_path_matches_golden_and_counts_fallback(cluster, tmp_path):
         assert r.status_code == 200 and r.content == payload
 
 
+def test_copy_path_under_chunk_delay_keeps_bytes(cluster, tmp_path):
+    """A per-chunk wire delay on the copy path (the `ec.copy.chunk`
+    failpoint in delay mode) slows VolumeEcShardsCopy and changes no
+    byte: every copied shard still hashes to the golden encode."""
+    master, volumes, env = cluster
+    vid, blobs, src = _make_volume(master, volumes, "cdly", seed=12)
+    base = _snapshot_dat(src, vid, tmp_path)
+    golden = _golden_hashes(base, TEST_GEO)
+
+    with failpoint.active("ec.copy.chunk", mode="delay", p=0.002) as fp:
+        _encode(env, vid, "-stream 0")
+        assert fp.hits > 0, "no copied chunk went through the delay"
+    _wait_ec_registered(master, vid)
+
+    got = _cluster_shard_hashes(volumes, vid, TEST_GEO, "cdly")
+    assert len(got) == TEST_GEO.total_shards
+    for sid, h in got.items():
+        assert h == golden[sid], f"shard {sid} diverged from golden"
+
+
 # -- destination digests (.dig) ---------------------------------------------
 
 def test_destination_digest_manifest_no_second_read(cluster, tmp_path):

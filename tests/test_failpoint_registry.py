@@ -5,7 +5,7 @@ either side silently turns the scenario into a no-op (the arm never
 fires, `hits` guards notwithstanding the suite only notices if every
 scenario carries one). This test closes both directions statically:
 
-* every name ARMED anywhere in tests/tools/bench must exist as a
+* every name ARMED anywhere in tests/tools must exist as a
   literal injection site in ``seaweedfs_tpu/`` (or be a valid dynamic
   ``pb.<Method>`` point — those are synthesized per RPC in pb/rpc.py);
 * every literal site in ``seaweedfs_tpu/`` must be armed by at least
@@ -51,8 +51,7 @@ def _sites() -> set[str]:
 def _armed() -> set[str]:
     files = list((REPO / "tests").glob("*.py"))
     files += list((REPO / "tools").glob("*.py"))
-    files.append(REPO / "bench.py")
-    return _scan([p for p in files if p.exists()], [_ARM_RE, _SPEC_RE])
+    return _scan(files, [_ARM_RE, _SPEC_RE])
 
 
 def _pb_methods() -> set[str]:

@@ -124,28 +124,6 @@ def test_generate_ec_files_mesh_bit_identical(tmp_path):
                                payload_len=351_003, seed=9)
 
 
-def test_mesh_kernels_bit_identical():
-    """xor vs bits per-device formulations agree byte-for-byte on the mesh."""
-    import numpy as np
-
-    from seaweedfs_tpu.parallel.mesh import ShardedCoder, make_mesh
-
-    mesh = make_mesh()
-    rng = np.random.default_rng(21)
-    data = rng.integers(0, 256, size=(10, 4096), dtype=np.uint8)
-    out = {}
-    for kernel in ("xor", "bits"):
-        coder = ShardedCoder(10, 4, mesh=mesh, kernel=kernel)
-        shards = np.asarray(coder.encode(data))
-        present = {i: shards[i] for i in range(14) if i not in (2, 7, 13)}
-        rebuilt = coder.reconstruct(present)
-        out[kernel] = (shards, {i: np.asarray(v) for i, v in rebuilt.items()})
-        assert int(np.asarray(coder.parity_checksum(shards))) == 0
-    np.testing.assert_array_equal(out["xor"][0], out["bits"][0])
-    for i in (2, 7, 13):
-        np.testing.assert_array_equal(out["xor"][1][i], out["bits"][1][i])
-
-
 def test_generate_ec_files_mesh_production_ratio_boundary(tmp_path):
     """Mesh-sharded encode across a REAL large-row -> small-row boundary
     at the production 1024:1 block ratio (1GB:1MB scaled to 1MB:1KB —
@@ -218,19 +196,15 @@ def test_geometry_arithmetic_at_true_production_constants():
 
 
 @pytest.mark.parametrize("path", ["mesh-columns", "mesh-vstack",
-                                  "mesh-vsharded-rebuild", "mesh-probe",
-                                  "xor-xla", "sel-xla"])
+                                  "mesh-vsharded-rebuild", "mesh-probe"])
 def test_dispatch_width_bound_keeps_bytes(path, mesh, monkeypatch):
-    """Work wider than WORD_KERNEL_COLS (what one v5e dispatch of the
-    word-packed kernels fits) is split into several dispatches inside
-    the coder; the bytes match the rs_cpu oracle exactly. The bound is
+    """Work wider than MESH_DISPATCH_COLS per device (what one v5e mesh
+    dispatch is held to) is split into several dispatches inside the
+    coder; the bytes match the rs_cpu oracle exactly. The bound is
     shrunk here so small arrays cross it."""
-    from seaweedfs_tpu.ops import rs_jax
-    from seaweedfs_tpu.ops.rs_jax import RSCodecJax
     from seaweedfs_tpu.parallel import mesh as mesh_mod
 
-    monkeypatch.setattr(rs_jax, "WORD_KERNEL_COLS", 256)
-    monkeypatch.setattr(mesh_mod, "WORD_KERNEL_COLS", 256)
+    monkeypatch.setattr(mesh_mod, "MESH_DISPATCH_COLS", 256)
     rng = np.random.default_rng(11)
     ref = RSCodecCPU(10, 4)
     coder = ShardedCoder(10, 4, mesh=mesh)
@@ -255,14 +229,75 @@ def test_dispatch_width_bound_keeps_bytes(path, mesh, monkeypatch):
         for v in range(9):
             assert np.array_equal(
                 rows[v], shards[list(missing), v * 600:(v + 1) * 600])
-    elif path == "mesh-probe":
+    else:
         data = rng.integers(0, 256, (10, 8 * 256 * 2 + 8), dtype=np.uint8)
         shards = np.concatenate([data, ref.encode_parity(data)])
         assert int(coder.parity_probe(shards)) == 0
         shards[12, -1] ^= 0x5A
         assert int(coder.parity_probe(shards)) == 0x5A
+
+
+@pytest.fixture(scope="module")
+def coder4():
+    """The mesh coder on the first four devices: a v5e 2x2 host's
+    layout."""
+    from seaweedfs_tpu.parallel.mesh import local_devices
+
+    return ShardedCoder(10, 4, mesh=make_mesh(local_devices()[:4]))
+
+
+@pytest.mark.parametrize("op", ["encode", "reconstruct",
+                                "reconstruct_stacked_vsharded",
+                                "parity_probe"])
+def test_mesh4_matches_cpu(coder4, op):
+    """Each shard_map program of the 2x2 mesh coder against rs_cpu."""
+    rng = np.random.default_rng(70)
+    ref = RSCodecCPU(10, 4)
+    data = rng.integers(0, 256, (10, 4 * 1000 + 24), dtype=np.uint8)
+    shards = np.concatenate([data, ref.encode_parity(data)])
+    lost = (2, 7, 13)
+    present = tuple(i for i in range(14) if i not in lost)
+    if op == "encode":
+        assert np.array_equal(np.asarray(coder4.encode(data)), shards)
+    elif op == "reconstruct":
+        got = coder4.reconstruct({i: shards[i] for i in present})
+        assert sorted(got) == list(lost)
+        for i in lost:
+            assert np.array_equal(np.asarray(got[i]), shards[i])
+    elif op == "reconstruct_stacked_vsharded":
+        width = 800
+        stack = np.stack([shards[list(present), v * width:(v + 1) * width]
+                          for v in range(5)])
+        missing, rows = coder4.reconstruct_stacked_vsharded(present, stack)
+        assert missing == lost
+        rows = np.asarray(rows)
+        for v in range(5):
+            assert np.array_equal(
+                rows[v], shards[list(lost), v * width:(v + 1) * width])
     else:
-        monkeypatch.setenv("SEAWEEDFS_TPU_KERNEL", path)
-        data = rng.integers(0, 256, (10, 256 * 3 + 12), dtype=np.uint8)
-        got = np.asarray(RSCodecJax(10, 4).encode_parity(data))
-        assert np.array_equal(got, ref.encode_parity(data))
+        assert int(coder4.parity_probe(shards)) == 0
+        shards[11, 17] ^= 0x21
+        assert int(coder4.parity_probe(shards)) == 0x21
+
+
+def test_mesh4_lrc_encode_and_local_repair():
+    """lrc_10_2_2 through the 2x2 mesh coder: parity matches rs_cpu, and
+    a lost data shard is rebuilt from its local group alone."""
+    from seaweedfs_tpu.models import geometry as gm
+    from seaweedfs_tpu.parallel.mesh import local_devices
+
+    lrc = gm.get("lrc_10_2_2")
+    coder = ShardedCoder(10, 4, mesh=make_mesh(local_devices()[:4]),
+                         geometry=lrc)
+    ref = RSCodecCPU(10, 4, geometry=lrc)
+    rng = np.random.default_rng(71)
+    data = rng.integers(0, 256, (10, 3000), dtype=np.uint8)
+    parity = np.asarray(coder.encode_parity(data))
+    assert np.array_equal(parity, ref.encode_parity(data))
+    shards = np.concatenate([data, parity])
+    plan = lrc.repair_plan((7,), tuple(i for i in range(14) if i != 7))
+    assert len(plan.reads) < 10, plan.reads
+    missing, rows = coder.reconstruct_stacked(
+        plan.reads, shards[list(plan.reads)], want=(7,))
+    assert tuple(missing) == (7,)
+    assert np.array_equal(np.asarray(rows)[0], shards[7])

@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from seaweedfs_tpu.ops import gf256, rs_jax, rs_pallas, rs_xor
+from seaweedfs_tpu.models import geometry as gm
+from seaweedfs_tpu.ops import rs_jax
 from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.storage.ec_files import DEFAULT_BATCH_SIZE
 
@@ -96,7 +97,7 @@ def test_mxu_xla_encode_fits(one_chip, slabs):
 def test_chip_lane_encode_fits(one_chip):
     """The per-chip lane's form: runtime bit-matrix operand, donated
     input, widest stack."""
-    bits = rs_jax.parity_matrix_op(K, M, "bits")
+    bits = rs_jax.parity_matrix_op(K, M)
     width = rs_jax._bucket(32 * SLAB)
     compiled = rs_jax._apply_matrix_jit_donated.lower(
         _sds(bits.shape, jnp.int8, one_chip),
@@ -108,8 +109,7 @@ def test_fused_reconstruct_fits(one_chip):
     """3 lost shards rebuilt from the 11 survivors in one fused
     column-permuted matmul, at one slab."""
     present = tuple(i for i in range(K + M) if i not in LOST)
-    missing, op = rs_jax.fused_reconstruct_stacked_op(K, M, present, K + M,
-                                                      "bits")
+    missing, op = rs_jax.fused_reconstruct_stacked_op(K, M, present, K + M)
     assert missing == LOST
     compiled = rs_jax._apply_matrix_jit.lower(
         _sds(op.shape, jnp.int8, one_chip),
@@ -117,54 +117,17 @@ def test_fused_reconstruct_fits(one_chip):
     _fits(compiled)
 
 
-def _lower_pallas(kind, one_chip):
-    gp = gf256.parity_matrix(K, M)
-    if kind == "mxu-pallas":
-        bits = rs_jax.gf_matrix_to_bits(gp)
-        return rs_pallas.gf_matmul_bits_pallas.lower(
-            _sds(bits.shape, jnp.int8, one_chip),
-            _sds((K, SLAB), jnp.uint8, one_chip), out_rows=M)
-    words = SLAB // 4
-    if kind == "xor-pallas":
-        coeffs = rs_xor.xor_coefficients(gp).reshape(M, -1)
-        return rs_xor.gf_matmul_xor_pallas.lower(
-            _sds(coeffs.shape, jnp.int32, one_chip),
-            _sds((K, words), jnp.int32, one_chip), out_rows=M)
-    run = rs_xor._sel_runner(gp, ("compile-rehearsal",), pallas=True,
-                             interpret=False)
-    return run.lower(_sds((K, words // rs_xor.LANE, rs_xor.LANE),
-                          jnp.int32, one_chip))
-
-
-@pytest.mark.parametrize("kind", [k for k in rs_jax._KERNELS
-                                  if k.endswith("-pallas")])
-def test_pallas_kernel_compiles(one_chip, kind):
-    """Every Pallas formulation SEAWEEDFS_TPU_KERNEL can select compiles
-    for the chip as a Mosaic kernel at one slab."""
-    compiled = _lower_pallas(kind, one_chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_fused_reconstruct_fits_at_rebuild_width(one_chip):
+    """The rebuild's minimal-read form: the lost shards solved from the
+    first k survivors (the `want` repair operand) over two slabs side by
+    side, [k, 8 MiB], the width the archive rebuild dispatches."""
+    reads = tuple(i for i in range(K + M) if i not in LOST)[:K]
+    op = rs_jax.geom_stacked_op(gm.rs(K, M), reads, LOST)
+    assert op.shape == (8 * len(LOST), 8 * K)
+    compiled = rs_jax._apply_matrix_jit.lower(
+        _sds(op.shape, jnp.int8, one_chip),
+        _sds((K, 2 * SLAB), jnp.uint8, one_chip)).compile()
     _fits(compiled)
-
-
-@pytest.mark.parametrize("kind", ["xor-xla", "sel-xla"])
-def test_word_packed_xla_fits_at_dispatch_bound(one_chip, kind):
-    """xor-xla / sel-xla repack bytes into int32 words, lane-padded on
-    the TPU (~640 B per column): one dispatch at the bound the codec
-    splits wider work by (rs_jax.WORD_KERNEL_COLS) must fit."""
-    width = rs_jax.WORD_KERNEL_COLS
-    gp = gf256.parity_matrix(K, M)
-    if kind == "xor-xla":
-        coeffs = rs_xor.xor_coefficients(gp)
-        lowered = rs_xor._matmul_xor_jit.lower(
-            _sds(coeffs.shape, jnp.int32, one_chip),
-            _sds((K, width), jnp.uint8, one_chip))
-    else:
-        run = rs_xor._sel_runner(gp, ("compile-rehearsal",), pallas=False,
-                                 interpret=False)
-        lowered = jax.jit(lambda d: rs_xor._to_bytes(
-            run(rs_xor._to_words(d)))).lower(
-                _sds((K, width), jnp.uint8, one_chip))
-    _fits(lowered.compile())
 
 
 @pytest.fixture(scope="module")
@@ -173,16 +136,16 @@ def mesh4(topo):
 
 
 def test_mesh_column_encode_fits(mesh4):
-    """ShardedCoder.encode_parity: the "xor" shard_map with byte columns
-    split over the 2x2 mesh, at the widest one dispatch takes
-    (WORD_KERNEL_COLS per device; wider work is split)."""
-    op = rs_jax.parity_matrix_op(K, M, "xor")
+    """ShardedCoder.encode_parity: the bitsliced shard_map with byte
+    columns split over the 2x2 mesh, at the widest one dispatch takes
+    (MESH_DISPATCH_COLS per device; wider work is split)."""
+    op = rs_jax.parity_matrix_op(K, M)
     axis = mesh4.axis_names[0]
-    width = 4 * rs_jax.WORD_KERNEL_COLS
+    width = 4 * mesh_mod.MESH_DISPATCH_COLS
     compiled = mesh_mod._apply_sharded.lower(
-        _sds(op.shape, jnp.int32, NamedSharding(mesh4, P())),
+        _sds(op.shape, jnp.int8, NamedSharding(mesh4, P())),
         _sds((K, width), jnp.uint8, NamedSharding(mesh4, P(None, axis))),
-        mesh4, axis, "xor").compile()
+        mesh4, axis).compile()
     _fits(compiled)
 
 
@@ -191,25 +154,25 @@ def test_mesh_vsharded_rebuild_fits(mesh4):
     the mesh through the fused reconstruct operand, one slab per chip
     per dispatch as ShardedCoder._apply_vstack sends them."""
     present = tuple(i for i in range(K + M) if i not in LOST)
-    _, op = rs_jax.fused_reconstruct_stacked_op(K, M, present, K + M, "xor")
+    _, op = rs_jax.fused_reconstruct_stacked_op(K, M, present, K + M)
     axis = mesh4.axis_names[0]
     v = 4
     compiled = mesh_mod._apply_stacked_vsharded.lower(
-        _sds(op.shape, jnp.int32, NamedSharding(mesh4, P())),
+        _sds(op.shape, jnp.int8, NamedSharding(mesh4, P())),
         _sds((v, len(present), SLAB), jnp.uint8,
              NamedSharding(mesh4, P(axis, None, None))),
-        mesh4, axis, "xor").compile()
+        mesh4, axis).compile()
     _fits(compiled)
 
 
 def test_mesh_parity_probe_fits(mesh4):
     """ShardedCoder.parity_probe: the pmax collective over the mesh."""
-    op = rs_jax.parity_matrix_op(K, M, "xor")
+    op = rs_jax.parity_matrix_op(K, M)
     axis = mesh4.axis_names[0]
     compiled = mesh_mod._parity_probe.lower(
-        _sds(op.shape, jnp.int32, NamedSharding(mesh4, P())),
-        _sds((K + M, 4 * rs_jax.WORD_KERNEL_COLS), jnp.uint8,
+        _sds(op.shape, jnp.int8, NamedSharding(mesh4, P())),
+        _sds((K + M, 4 * mesh_mod.MESH_DISPATCH_COLS), jnp.uint8,
              NamedSharding(mesh4, P(None, axis))),
-        mesh4, axis, K, "xor").compile()
+        mesh4, axis, K).compile()
     assert "all-reduce" in compiled.as_text()
     _fits(compiled)

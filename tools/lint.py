@@ -56,19 +56,17 @@ from analysis import (blocking as _blocking,  # noqa: E402
 # them: the gate's verdict must not depend on whether ruff is installed)
 RUFF_RULES = "E9,E711,E712,F63,F7,F82"
 
-LINT_TARGETS = ("seaweedfs_tpu", "tests", "tools", "bench.py",
-                "__graft_entry__.py")
+LINT_TARGETS = ("seaweedfs_tpu", "tests", "tools", "__graft_entry__.py")
 # machine-generated wire code (protoc output style) is not hand-lintable
 EXCLUDE_SUFFIX = "_pb2.py"
 
 # SWFS001 (ISSUE 5): bare jax.devices()/jax.local_devices() enumeration is
 # allowed ONLY here — device placement must go through the mesh helpers
 # (parallel/mesh.local_devices / device_count / make_mesh) so mesh policy
-# lives in one file; bench.py is exempt (it probes the backend on purpose).
-# Runs under BOTH the ruff path and the fallback (ruff has no such rule).
+# lives in one file. Runs under BOTH the ruff path and the fallback (ruff
+# has no such rule).
 DEVICE_ENUM_ALLOWED = (
     os.path.join("seaweedfs_tpu", "parallel", "mesh.py"),
-    "bench.py",
 )
 
 # SWFS002 (ISSUE 7): span timing inside the tracing plane must come from
