@@ -75,6 +75,7 @@ from ..utils.stats import (
     EC_DISPATCH_SLABS,
     EC_DISPATCH_STACK_BYTES,
     EC_DISPATCH_STACK_SLABS,
+    EC_DISPATCH_SUBMIT_COPIED,
     EC_DISPATCH_WINDOW_WAIT,
     EC_DISPATCH_ZEROFILL_ELIDED,
     EC_RECON_CACHE_COUNTER,
@@ -735,9 +736,11 @@ class EcDispatchScheduler:
     def encode_parity(self, data: np.ndarray, copy: bool = True) -> EcFuture:
         """Submit one [k, B] slab; the future resolves to parity [m, B].
 
-        `copy=True` (default) snapshots the slab: the encode pipeline
-        recycles its read buffers as soon as the data rows hit disk,
-        which can be before the stacked dispatch reads them.
+        `copy=True` (default) snapshots the slab, for callers that do
+        not hold their buffer until the future resolves (its bytes
+        count in `SeaweedFS_ec_dispatch_submit_copied_bytes{lane}`).
+        The encode file pipeline holds each slab's buffer until its
+        parity is host bytes and submits with `copy=False`.
 
         On a mesh coder, slabs round-robin over per-chip lanes — one
         pipeline alone fans across every chip, and N pipelines load the
@@ -745,6 +748,7 @@ class EcDispatchScheduler:
         data = np.asarray(data, dtype=np.uint8)
         if copy:
             data = data.copy()
+            EC_DISPATCH_SUBMIT_COPIED.inc(data.nbytes, lane="encode")
         chips = self._chip_list()
         if chips:
             key = ("enc", self.geom_id, next(self._enc_rr) % len(chips))
@@ -763,6 +767,8 @@ class EcDispatchScheduler:
         stacked = np.asarray(stacked, dtype=np.uint8)
         if copy:
             stacked = stacked.copy()
+            EC_DISPATCH_SUBMIT_COPIED.inc(stacked.nbytes,
+                                          lane="reconstruct")
         if want is not None and not hasattr(self.coder,
                                             "reconstruct_stacked"):
             raise TypeError(

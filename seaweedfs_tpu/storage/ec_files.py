@@ -18,6 +18,7 @@ bit-identical to the reference's 256KB batching.
 
 from __future__ import annotations
 
+import functools
 import os
 import queue
 import threading
@@ -28,8 +29,9 @@ import numpy as np
 
 from ..ops import dispatch
 from ..utils import fanout, numa, trace
-from ..utils.stats import (EC_PIPELINE_READ_QUEUE, EC_PIPELINE_READ_STREAMS,
-                           EC_PIPELINE_SECONDS, EC_PIPELINE_SLABS)
+from ..utils.stats import (EC_PIPELINE_BUFFER_WAIT, EC_PIPELINE_READ_QUEUE,
+                           EC_PIPELINE_READ_STREAMS, EC_PIPELINE_SECONDS,
+                           EC_PIPELINE_SLABS)
 from . import idx as idx_mod
 from . import needle_map, types
 from .ec_locate import Geometry
@@ -198,6 +200,17 @@ class _Phases:
     def _observe_queue(self, seconds: float) -> None:
         EC_PIPELINE_READ_QUEUE.inc(seconds, op=self.op)
 
+    def buffer_wait(self):
+        """On the reader thread, before a slab's `read`: blocked until
+        the pool hands back a recycled buffer. Its span is a child of
+        the pipeline's root span; its seconds go to
+        `SeaweedFS_ec_pipeline_buffer_wait_seconds{op}` alone."""
+        return trace.timed(f"ec.{self.op}.buffer_wait", parent=self.parent,
+                           child_only=True, observe=self._observe_buffer)
+
+    def _observe_buffer(self, seconds: float) -> None:
+        EC_PIPELINE_BUFFER_WAIT.inc(seconds, op=self.op)
+
     def slab(self) -> None:
         """One slab through the coordinator."""
         self.stats.batches += 1
@@ -207,8 +220,8 @@ class _Phases:
 class _ShardWriters:
     """Shard files fanned out over writer threads; each shard maps to
     exactly one thread, so per-shard write order is preserved while
-    independent files stream in parallel. Blocks of one slab release the
-    recycled read buffer via a countdown once every data-shard row is on
+    independent files stream in parallel. Each data-shard row of a slab
+    counts down its recycled read buffer's `_Countdown` once it is on
     disk. Each row written is one `write` phase of `phases`."""
 
     def __init__(self, files: dict[int, object], phases: _Phases,
@@ -302,8 +315,11 @@ class _ShardWriters:
 
 
 class _Countdown:
-    """Call `cb` after `n` release() calls — frees a recycled read buffer
-    only when every data-shard writer has flushed its row view."""
+    """Call `cb` after `n` calls. The encode pipeline frees a recycled
+    read buffer with one of k + 1: a call from each data-shard writer
+    once its row is on disk, and one from the coordinator once the
+    slab's parity is host bytes (the device has read the slab by then,
+    which the scheduler references without a copy)."""
 
     __slots__ = ("_n", "_cb", "_lock")
 
@@ -362,10 +378,12 @@ def generate_ec_files(
     A slab is whole before it is submitted or handed to `sinks`. A
     recycled buffer pool caps host memory at ~(depth+2) slabs; a slab's
     buffer is recycled only after every data-shard writer flushed its row
-    (countdown). Multiple volumes encoding concurrently (BASELINE config
-    #4) each run their own pipeline; their encode launches interleave on
-    the shared device queue, so host I/O of one volume overlaps device
-    math of another.
+    and its parity is host bytes (countdown), so the slab is submitted
+    as a C-contiguous view of its buffer, without a copy. The reader's
+    wait for a buffer is `ec.encode.buffer_wait`. Multiple volumes
+    encoding concurrently (BASELINE config #4) each run their own
+    pipeline; their encode launches interleave on the shared device
+    queue, so host I/O of one volume overlaps device math of another.
 
     `sinks` (ISSUE 6, storage/ec_stream.py EcStreamSinkSet) is the
     pluggable shard-sink hook: an object whose
@@ -418,8 +436,11 @@ def generate_ec_files(
     # stacked [V, k, B] device dispatches. The futures it returns answer
     # np.asarray just like the lazy device array from a direct call, and
     # shard bytes stay identical (zero-padded ragged columns slice away).
+    # No snapshot at submit: the slab's buffer is not recycled before its
+    # parity is host bytes (the coordinator's release of the countdown).
     sched = dispatch.maybe_scheduler(coder)
-    encode = coder.encode_parity if sched is None else sched.encode_parity
+    encode = (coder.encode_parity if sched is None
+              else functools.partial(sched.encode_parity, copy=False))
     # one node per PIPELINE (ISSUE 12): reader and writers share it, so
     # the recycled read buffers stay node-local end to end; separate
     # concurrent pipelines round-robin across nodes via their own draws
@@ -436,10 +457,13 @@ def generate_ec_files(
                     for b in range(0, block_size, batch):
                         if stop.is_set():
                             return
-                        buf = free_q.get()
+                        with phases.buffer_wait():
+                            buf = free_q.get()
                         if stop.is_set() or buf.shape[1] < batch:
                             return
-                        data = buf[:, :batch]
+                        # the slab's k rows packed at the buffer's start:
+                        # one dense [k, batch] block for the transfer
+                        data = buf.reshape(-1)[:k * batch].reshape(k, batch)
                         # row i of the slab is .dat bytes from `start`;
                         # ReadAt with zero fill past EOF
                         # (ec_encoder.go:165-177)
@@ -484,18 +508,19 @@ def generate_ec_files(
                 # the bounded queue depth); a raise aborts the pipeline
                 # through the normal failure path below
                 pace(k * nbytes)
-            release = _Countdown(k, lambda b=buf: free_q.put(b))
+            release = _Countdown(k + 1, lambda b=buf: free_q.put(b))
             if sinks is not None:
                 # data rows stream BEFORE the writers get the buffer:
-                # sinks copy synchronously here, and once writers.put
-                # hands rows to the writer threads the countdown can
-                # recycle the buffer under a concurrent reader refill
+                # sinks copy synchronously here, since the countdown
+                # recycles the buffer under a concurrent reader refill
+                # once the writers and this thread have released it
                 for i in range(k):
                     sinks.put(i, shard_off, data[i], nbytes)
             for i in range(k):
                 writers.put(i, data[i], nbytes, release)
             with phases.timed("device_wait"):
                 parity = np.asarray(parity_fut)  # blocks until device done
+            release()  # the device has read the slab
             for j in range(m):
                 # parity rows are views of one fresh array; numpy refcounts
                 # keep it alive until the last writer drops its view
@@ -708,7 +733,8 @@ def rebuild_ec_files(
                                   f"{shard_size} got {size}")
             offset = 0
             while offset < shard_size and not stop.is_set():
-                stacked = free_q.get()
+                with phases.buffer_wait():
+                    stacked = free_q.get()
                 if stacked is None or stop.is_set():
                     return
                 n = min(cols, shard_size - offset)

@@ -338,6 +338,12 @@ EC_DISPATCH_OVERLAPPED = Counter(
     "another chip's submission of the same scheduler was still under "
     "way, by lane and chip; over SeaweedFS_ec_dispatch_batches, the "
     "share of batches the per-chip submission locks let overlap.")
+EC_DISPATCH_SUBMIT_COPIED = Counter(
+    "SeaweedFS_ec_dispatch_submit_copied_bytes",
+    "Bytes the EC dispatch scheduler snapshot at submit, by lane: the "
+    "slabs of callers that do not hold their buffer until the slab's "
+    "future resolves (a scrub's encode); the encode and rebuild file "
+    "pipelines hold theirs and submit without a copy.")
 EC_DISPATCH_STACK_SLABS = Histogram(
     "SeaweedFS_ec_dispatch_stacked_slabs",
     "Slabs per stacked dispatch (the realized batch size).",
@@ -378,6 +384,12 @@ EC_PIPELINE_READ_QUEUE = Counter(
     "Seconds the EC file pipeline's slabs queued on the shared ec_read "
     "pool, by op (encode/rebuild): per slab, from handing its reads to "
     "the pool until the first of them started; part of the read phase.")
+EC_PIPELINE_BUFFER_WAIT = Counter(
+    "SeaweedFS_ec_pipeline_buffer_wait_seconds",
+    "Seconds the EC file pipeline's reader thread waited for a recycled "
+    "slab buffer, by op (encode/rebuild): a buffer comes back only once "
+    "its slab's rows are host bytes (and, for the encode, its data rows "
+    "are on disk); not part of any phase.")
 
 # -- compiled XOR-schedule codec plane (ISSUE 17): generator matrices
 #    lowered to cached bit-plane XOR programs for the host CPU path ------

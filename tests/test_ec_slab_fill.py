@@ -10,9 +10,11 @@ reference's committed 1.dat fixture where it is present.
 """
 
 import os
+import queue
 import shutil
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -215,6 +217,148 @@ def test_rebuild_reuses_every_buffer_and_matches(
     want = _oracle_shards(dat)
     for i in lost:
         assert after[i] == before[i] == want[i], f"rebuilt shard {i} differs"
+
+
+class _LateEncodeCoder:
+    """An encode coder over the rs_cpu oracle whose parity reads the
+    slab it was handed only when asked for as host bytes, after a pause:
+    a device that has not read its input yet when the slab's data rows
+    are on disk. It has no stacked entry, so the scheduler hands it
+    each slab as submitted, batched or not."""
+
+    def __init__(self):
+        self.data_shards, self.parity_shards = K, M
+        self._oracle = RSCodecCPU(K, M)
+
+    def encode_parity(self, data):
+        return _LateParity(self._oracle, data)
+
+
+class _LateParity:
+    def __init__(self, oracle, data):
+        self._oracle, self._data = oracle, data
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.01)
+        return np.asarray(self._oracle.encode_parity(self._data), dtype)
+
+
+def _large_and_small(base: str) -> bytes:
+    """A .dat of large and small rows whose last slab is ragged."""
+    _make_synthetic_volume(base, n_needles=80, seed=24)
+    with open(base + ".dat", "rb") as f:
+        dat = f.read()
+    n_large, n_small = GEO.row_counts(len(dat))
+    assert n_large >= 1 and n_small >= 2
+    assert len(dat) % GEO.small_block
+    return dat
+
+
+def _encode_within(timeout_s: float, *args, **kw) -> ec_files.EncodeStats:
+    """generate_ec_files on a thread of its own: a pipeline that
+    deadlocks fails the test after `timeout_s` instead of hanging it."""
+    out: list = []
+
+    def run():
+        try:
+            out.append(ec_files.generate_ec_files(*args, **kw))
+        except BaseException as e:  # surfaced by the assert below
+            out.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout_s)
+    assert not t.is_alive(), f"encode still running after {timeout_s} s"
+    assert isinstance(out[0], ec_files.EncodeStats), out
+    return out[0]
+
+
+class _UnboundedQueue(queue.Queue):
+    def __init__(self, maxsize: int = 0):
+        super().__init__()
+
+
+@pytest.mark.parametrize("work_queue", ["bounded", "unbounded"])
+@pytest.mark.parametrize("depth", [1, 3])
+def test_encode_buffer_held_until_parity_is_host_bytes(tmp_path, monkeypatch,
+                                                       depth, work_queue):
+    """Through a real dispatch scheduler, a coder that reads each slab
+    only once its parity is asked for still gives the oracle's shards:
+    a slab's read buffer is not refilled before its parity is host
+    bytes, whatever the pipeline depth. With the pipeline's queues
+    unbounded the reader runs as far ahead as the buffer pool lets it,
+    so the buffer's hold is the only thing that keeps it from refilling
+    a slab the coder has not read yet."""
+    monkeypatch.setenv("SWFS_EC_DISPATCH", "1")
+    if work_queue == "unbounded":
+        monkeypatch.setattr(ec_files, "queue", types.SimpleNamespace(
+            Queue=_UnboundedQueue, Empty=queue.Empty, Full=queue.Full))
+    base = str(tmp_path / "h")
+    dat = _large_and_small(base)
+    coder = _LateEncodeCoder()
+    sched = ec_files.dispatch.scheduler_for(coder)
+    try:
+        _encode_within(120, base, coder, GEO, batch_size=5000,
+                       pipeline_depth=depth)
+    finally:
+        sched.close()
+    want = _oracle_shards(dat)
+    got = _shards(base)
+    for i in range(K + M):
+        assert got[i] == want[i], f"shard {i} differs from the oracle"
+
+
+def test_encode_submits_pool_buffers_without_copy(tmp_path, monkeypatch):
+    """The encode pipeline hands the scheduler C-contiguous views of its
+    pool buffers and the scheduler copies none of them; a scrub-style
+    submit, with the default, still snapshots its slab and counts the
+    bytes in `SeaweedFS_ec_dispatch_submit_copied_bytes{lane}`."""
+    monkeypatch.setenv("SWFS_EC_DISPATCH", "1")
+    base = str(tmp_path / "n")
+    dat = _large_and_small(base)
+    coder = new_coder(K, M, "cpu")
+    sched = ec_files.dispatch.scheduler_for(coder)
+    submitted: list[np.ndarray] = []
+    real_submit = sched._submit
+
+    def submit(key, data, chip=None):
+        submitted.append(data)
+        return real_submit(key, data, chip=chip)
+
+    monkeypatch.setattr(sched, "_submit", submit)
+    pool: dict[int, np.ndarray] = {}
+    real_rows = ec_files._pread_rows
+
+    def rows(reads, streams, phases):
+        buf = reads[0][2].obj.base  # row 0 of a slab: never past EOF here
+        pool[id(buf)] = buf
+        return real_rows(reads, streams, phases)
+
+    monkeypatch.setattr(ec_files, "_pread_rows", rows)
+    copied0 = stats.EC_DISPATCH_SUBMIT_COPIED.value(lane="encode")
+    try:
+        st = _encode_within(120, base, coder, GEO, batch_size=5000)
+        assert stats.EC_DISPATCH_SUBMIT_COPIED.value(lane="encode") == copied0
+        assert len(pool) == DEPTH + 2
+        assert len(submitted) == st.batches > len(pool)
+        for slab in submitted:
+            assert slab.flags.c_contiguous
+            assert any(np.shares_memory(slab, b) for b in pool.values())
+        want = _oracle_shards(dat)
+        got = _shards(base)
+        for i in range(K + M):
+            assert got[i] == want[i], f"shard {i} differs from the oracle"
+
+        scrub = np.random.default_rng(25).integers(0, 256, (K, 300),
+                                                   dtype=np.uint8)
+        fut = sched.encode_parity(scrub)
+        assert stats.EC_DISPATCH_SUBMIT_COPIED.value(lane="encode") \
+            == copied0 + scrub.nbytes
+        assert not np.shares_memory(submitted[-1], scrub)
+        assert np.array_equal(np.asarray(fut),
+                              RSCodecCPU(K, M).encode_parity(scrub))
+    finally:
+        sched.close()
 
 
 POOL_THREAD = "swfs-fanout-ec_read"

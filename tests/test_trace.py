@@ -393,6 +393,35 @@ def test_read_queue_span_once_per_slab(tmp_path, monkeypatch):
             >= sum(q.duration_ms for q in queues) / 1e3 - 1e-9
 
 
+def test_buffer_wait_span_once_per_slab(tmp_path, monkeypatch):
+    """The reader thread's wait for each slab's recycled buffer is one
+    `ec.<op>.buffer_wait` span, a child of the pipeline's root span and
+    closed before the slab's `ec.<op>.read` opens, for the encode and
+    the rebuild; `SeaweedFS_ec_pipeline_buffer_wait_seconds{op}` grows
+    by their readings (other pipelines of the process may add to it)."""
+    from seaweedfs_tpu.utils import stats
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)
+    waited0 = {op: stats.EC_PIPELINE_BUFFER_WAIT.value(op=op)
+               for op in ("encode", "rebuild")}
+    slabs0 = {op: stats.EC_PIPELINE_SLABS.value(op=op)
+              for op in ("encode", "rebuild")}
+    for op, root in _encode_and_rebuild(tmp_path).items():
+        spans = _ring_spans(root.trace_id)
+        reads = sorted((s for s in spans if s.name == f"ec.{op}.read"),
+                       key=lambda s: s._t0)
+        waits = sorted((s for s in spans
+                        if s.name == f"ec.{op}.buffer_wait"),
+                       key=lambda s: s._t0)
+        slabs = stats.EC_PIPELINE_SLABS.value(op=op) - slabs0[op]
+        assert slabs >= 2 and len(reads) == len(waits) == slabs
+        assert {w.parent_id for w in waits} == {root.span_id}
+        for wait, read in zip(waits, reads):
+            assert wait._t0 + wait.duration_ms / 1e3 <= read._t0
+        assert stats.EC_PIPELINE_BUFFER_WAIT.value(op=op) - waited0[op] \
+            >= sum(w.duration_ms for w in waits) / 1e3 - 1e-9
+
+
 def test_disabled_plane_records_no_lock_wait_or_read_queue(tmp_path,
                                                            monkeypatch):
     """With SWFS_TRACE=0 the lock wait and the read queue write nothing,
